@@ -206,16 +206,14 @@ def _sharded_fn(job: MapReduceJob, mesh, axis: str, n_extra: int):
     support jobs — hit the same compiled program whenever shapes repeat,
     exactly like the single-device DataPlane's jit-cache discipline.
     """
-    from jax.experimental.shard_map import shard_map
-
     def shard_body(x, *extra):
         v = job.map_fn(x, *extra)
         return jax.tree.map(lambda a: jax.lax.psum(a, axis), v)
 
     spec_out = jax.tree.map(lambda _: P(), job.zero_fn())
-    f = shard_map(shard_body, mesh=mesh,
-                  in_specs=(P(axis),) + (P(),) * n_extra,
-                  out_specs=spec_out, check_rep=False)
+    f = jax.shard_map(shard_body, mesh=mesh,
+                      in_specs=(P(axis),) + (P(),) * n_extra,
+                      out_specs=spec_out, check_vma=False)
     return jax.jit(f)
 
 

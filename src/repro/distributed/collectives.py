@@ -12,7 +12,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.compat import axis_size as _axis_size
 
 
 def ring_all_gather(x: jnp.ndarray, axis_name: str,
@@ -22,7 +21,7 @@ def ring_all_gather(x: jnp.ndarray, axis_name: str,
     ring).  If `compute` is given it is called with each arriving shard —
     the overlap hook: on hardware each hop's DMA runs concurrently with
     consuming the previous shard."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     shards = [x]

@@ -35,7 +35,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.hetero import HeterogeneityProfile
 from repro.core.mapreduce import MapReduceJob, run_sharded
@@ -261,6 +261,8 @@ class ShardedMiner:
         self.scheduler = self.runtime.scheduler
         self.power = self.runtime.power
         self.backend = resolve_backend(self.config.data_plane)
+        # rank d's slab lives on mesh device d: the shard_map input layout
+        self.data_sharding = NamedSharding(self.mesh, P(self.axis))
         self.row_block = row_block
         self.verify_rounds = verify_rounds
         # stable job objects -> run_sharded's compiled-program cache hits
@@ -337,6 +339,16 @@ class ShardedMiner:
             assignment=self.runtime.pinned_assignment(costs),
             extra_switches=switches, extra_reissued=reissued)
 
+    def _stage(self, slab: np.ndarray, report: PipelineReport) -> jax.Array:
+        """Upload a rank-major slab so each device receives only its own
+        rank's rows; the report records which device holds each rank."""
+        data = self.runtime.meter.h2d(slab, sharding=self.data_sharding)
+        report.shard_devices = [
+            int(s.device.id) for s in
+            sorted(data.addressable_shards,
+                   key=lambda s: s.index[0].start or 0)]
+        return data
+
     def _serial(self, name: str, cost: float, fn=None):
         # driver phases execute on the host co-located with rank 0
         return self.runtime.run_serial(name, cost, fn=fn, device=0)
@@ -380,7 +392,7 @@ class ShardedMiner:
         self.scheduler.switches += switches + reissued
         report.replans += 1
         report.shard_rows = [int(r) for r in new_plan.rows]
-        return (new_plan, self.runtime.meter.h2d(shard_bitmap(T, new_plan)),
+        return (new_plan, self._stage(shard_bitmap(T, new_plan), report),
                 switches, reissued, newly_dead)
 
     def _check_round(self, k: int, T: np.ndarray, C_padded: Optional[np.ndarray],
@@ -454,8 +466,6 @@ class ShardedMiner:
         alive = np.ones(n, dtype=bool)
         plan = plan_shards(self.profile, n_tx, row_block=self.row_block,
                            alive=alive)
-        data = rt.meter.h2d(shard_bitmap(T, plan))
-
         report = PipelineReport(
             backend=self.backend, policy=rt.policy.name, split=rt.split,
             profile_speeds=[float(s) for s in self.profile.speeds],
@@ -463,6 +473,7 @@ class ShardedMiner:
             n_tiles=plan.n_blocks, min_support=min_sup,
             execution="sharded", n_shards=n,
             shard_rows=[int(r) for r in plan.rows])
+        data = self._stage(shard_bitmap(T, plan), report)
         supports = {}
 
         # ---- round k=1: item frequency (<item, count>) ----------------
@@ -585,7 +596,6 @@ class ShardedMiner:
         alive = np.ones(n, dtype=bool)
         plan = plan_shards(self.profile, Tw.shape[0], row_block=1,
                            alive=alive)
-        data = rt.meter.h2d(shard_bitmap(Tw, plan))
         word_bytes = 4 * n_items_pad              # cost units: real-row bytes
 
         report = PipelineReport(
@@ -596,6 +606,7 @@ class ShardedMiner:
             n_tiles=plan.n_blocks, min_support=min_sup,
             execution="sharded", n_shards=n,
             shard_rows=[int(r) for r in plan.rows])
+        data = self._stage(shard_bitmap(Tw, plan), report)
         supports = {}
 
         # ---- round k=1: per-item column popcounts ----------------------

@@ -180,9 +180,23 @@ def default_cache(reload: bool = False) -> AutotuneCache:
     return _default
 
 
+def _resolve(kernel: str, shape: Tuple[int, ...],
+             tuning: Any) -> Tuple[Dict[str, Any], str]:
+    """(config, source) with source ``pinned`` | ``cache`` | ``roofline``."""
+    from repro.launch.tuning import default_config
+    if isinstance(tuning, dict):
+        return dict(tuning), "pinned"
+    if tuning is not False:
+        cache = tuning if isinstance(tuning, AutotuneCache) else default_cache()
+        entry = cache.lookup(kernel, shape)
+        if entry is not None:
+            return dict(entry["config"]), "cache"
+    return default_config(kernel, shape), "roofline"
+
+
 def resolve_config(kernel: str, shape: Tuple[int, ...],
                    tuning: Any = None) -> Dict[str, Any]:
-    """The single dispatch point the ops wrappers call per kernel launch.
+    """The config one kernel launch at ``shape`` runs with.
 
     ``tuning`` selects the source of the config:
       * ``None``  — the checked-in default cache (autotuning ON);
@@ -194,13 +208,25 @@ def resolve_config(kernel: str, shape: Tuple[int, ...],
     Cache misses — including cold/corrupt caches and unknown device
     kinds — fall back to :func:`repro.launch.tuning.default_config`.
     """
-    from repro.launch.tuning import default_config
-    if isinstance(tuning, dict):
-        return dict(tuning)
-    if tuning is False:
-        return default_config(kernel, shape)
-    cache = tuning if isinstance(tuning, AutotuneCache) else default_cache()
-    entry = cache.lookup(kernel, shape)
-    if entry is not None:
-        return dict(entry["config"])
-    return default_config(kernel, shape)
+    return _resolve(kernel, shape, tuning)[0]
+
+
+# kernel -> what its ops wrapper last launched: the padded shape, the
+# config, where the config came from and whether Pallas ran interpreted.
+# Read by chip_smoke.py to prove the chip ran compiled kernels.
+LAST_DISPATCH: Dict[str, Dict[str, Any]] = {}
+
+
+def dispatch(kernel: str, shape: Tuple[int, ...], tuning: Any,
+             interpret: Optional[bool]) -> Tuple[Dict[str, Any], bool]:
+    """The single dispatch point the ops wrappers call per kernel launch:
+    the config (see :func:`resolve_config`) and the interpret flag (default:
+    interpret everywhere but on a TPU), both recorded in ``LAST_DISPATCH``."""
+    import jax
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    cfg, source = _resolve(kernel, shape, tuning)
+    LAST_DISPATCH[kernel] = {"shape": tuple(int(d) for d in shape),
+                             "config": dict(cfg), "source": source,
+                             "interpret": bool(interpret)}
+    return cfg, bool(interpret)

@@ -16,8 +16,10 @@ Tiling (HBM→VMEM):
   grid = (B/bb, R/br): each [bb, br] output block is owned by exactly one
   grid point (no revisits), so both axes are parallel and Pallas' grid
   pipeline double-buffers the block DMAs.  The word axis rides whole per
-  block (W = I/32 is lanes-small), bounding VMEM by the [bb, br, W]
-  popcount intermediate.
+  block (W = I/32 is lanes-small); antecedent words arrive transposed,
+  ``[W, br]``, and the body walks ROW_CHUNK query rows at a time, one
+  word at a time (see :func:`repro.kernels.support_count.fused.
+  popcount_dots`), so VMEM holds little beyond the blocks.
 
 Padding contract (identical to the MXU variant): padded rule rows carry
 ``sizes = -1`` so they can never match — popcounts are >= 0 — and
@@ -32,16 +34,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.support_count.fused import _popcount_dots, pack_words
+from repro.kernels.support_count.fused import (ROW_CHUNK, as_word_lanes,
+                                               chunk_rows, pack_words,
+                                               popcount_dots)
 
 __all__ = ["pack_words", "rule_scores_fused_pallas", "rule_scores_fused"]
 
 
-def _kernel(q_ref, a_ref, sizes_ref, conf_ref, out_ref):
+def _kernel(q_ref, at_ref, sizes_ref, conf_ref, out_ref):
     """Grid: (i, j) over (B-tiles, R-tiles); every block owned once."""
-    dots = _popcount_dots(q_ref[...], a_ref[...])           # [bb, br] i32
-    match = (dots == sizes_ref[...]).astype(jnp.float32)    # -1 never hits
-    out_ref[...] = match * conf_ref[...]
+    sizes, conf = sizes_ref[...], conf_ref[...]             # [1, br]
+
+    def chunk(r, carry):
+        rows = chunk_rows(r)
+        dots = popcount_dots(q_ref, at_ref, rows)           # [8, br] i32
+        out_ref[rows, :] = (dots == sizes).astype(jnp.float32) * conf
+        return carry                                        # -1 never hits
+
+    jax.lax.fori_loop(0, q_ref.shape[0] // ROW_CHUNK, chunk, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "br", "interpret"))
@@ -50,27 +60,30 @@ def rule_scores_fused_pallas(Qw: jnp.ndarray, Aw: jnp.ndarray,
                              bb: int = 256, br: int = 256,
                              interpret: bool = False) -> jnp.ndarray:
     """Qw: [B, W] uint32; Aw: [R, W] uint32; sizes: [1, R] i32;
-    conf: [1, R] f32 -> [B, R] f32 confidence-weighted match scores."""
+    conf: [1, R] f32 -> [B, R] f32 confidence-weighted match scores.
+    B must be a multiple of ROW_CHUNK."""
     B, W = Qw.shape
     R = Aw.shape[0]
     bb, br = min(bb, B), min(br, R)
-    assert B % bb == 0 and R % br == 0, (Qw.shape, Aw.shape, (bb, br))
+    assert B % bb == 0 and R % br == 0 and bb % ROW_CHUNK == 0, \
+        (Qw.shape, Aw.shape, (bb, br))
     grid = (B // bb, R // br)
     return pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bb, W), lambda i, j: (i, 0)),
-            pl.BlockSpec((br, W), lambda i, j: (j, 0)),
+            pl.BlockSpec((W, br), lambda i, j: (0, j)),
             pl.BlockSpec((1, br), lambda i, j: (0, j)),
             pl.BlockSpec((1, br), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bb, br), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, R), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(Qw, Aw, sizes, conf)
+    )(jax.lax.bitcast_convert_type(Qw, jnp.int32), as_word_lanes(Aw),
+      sizes.astype(jnp.int32), conf)
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "br", "interpret"))

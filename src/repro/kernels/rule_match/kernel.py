@@ -13,14 +13,15 @@ compare/select, so batched serving inherits matmul arithmetic intensity.
 
 Tiling (HBM→VMEM):
   grid = (B/bb, R/br, I/bi) — item (contraction) axis innermost so the
-  [bb, br] f32 accumulator lives in VMEM scratch across the k-loop; on the
+  [bb, br] int32 accumulator lives in VMEM scratch across the k-loop; on the
   last item-tile we compare against |A_r| and write the confidence-weighted
   match block straight to the [bb, br] output tile (each output block is
   owned by exactly one (i, j), so no cross-grid revisits).
 
 Block defaults (bb=256, br=256, bi=512, int8 inputs):
-  VMEM ≈ 256·512 (Q) + 256·512 (A) + 256·256·4 (acc f32) + 256·256·4 (out)
-       + small ≈ 0.8 MiB ✓; MXU 256×512×256 int8 dots, lane-aligned.
+  VMEM ≈ 2·256·512 (Q) + 2·256·512 (A) + 256·256·4 (acc i32)
+       + 2·256·256·4 (out) + small ≈ 1.3 MiB ✓; MXU 256×512×256 int8
+       dots with int32 accumulation, lane-aligned.
 
 Padding contract (enforced by ops.py / the rule index): padded rule rows
 carry ``sizes = -1`` so they can never match (an all-zero antecedent would
@@ -45,11 +46,11 @@ def _kernel(q_ref, a_ref, sizes_ref, conf_ref, out_ref, acc_ref):
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # int8 x int8 -> f32 accumulate on the MXU
+    # int8 x int8 -> int32 accumulate on the MXU
     acc_ref[...] += jax.lax.dot_general(
         q_ref[...], a_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.int32,
     )
 
     @pl.when(l == nl - 1)
@@ -62,7 +63,8 @@ def _kernel(q_ref, a_ref, sizes_ref, conf_ref, out_ref, acc_ref):
 def rule_scores_pallas(Q: jnp.ndarray, A: jnp.ndarray, sizes: jnp.ndarray,
                        conf: jnp.ndarray, *, bb: int = 256, br: int = 256,
                        bi: int = 512, interpret: bool = False) -> jnp.ndarray:
-    """Q: [B, I] int8; A: [R, I] int8; sizes/conf: [1, R] f32 -> [B, R] f32."""
+    """Q: [B, I] int8; A: [R, I] int8; sizes: [1, R] (=|A_r|, -1 on padding;
+    compared as int32); conf: [1, R] f32 -> [B, R] f32."""
     B, I = Q.shape
     R = A.shape[0]
     bb, br, bi = min(bb, B), min(br, R), min(bi, I)
@@ -80,6 +82,8 @@ def rule_scores_pallas(Q: jnp.ndarray, A: jnp.ndarray, sizes: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bb, br), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, R), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bb, br), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bb, br), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(Q, A, sizes, conf)
+    )(Q, A, sizes.astype(jnp.int32), conf)

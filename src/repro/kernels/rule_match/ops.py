@@ -21,7 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.autotune.cache import resolve_config
+from repro.kernels.autotune.cache import dispatch
 from repro.kernels.rule_match.fused import rule_scores_fused
 from repro.kernels.rule_match.kernel import rule_scores_pallas
 from repro.kernels.rule_match.ref import (recommend_ref, rule_scores_ref,
@@ -84,8 +84,6 @@ def rule_topk(Q: jnp.ndarray, A: jnp.ndarray, sizes: jnp.ndarray,
     """
     if backend is None:
         backend = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B0, I0 = Q.shape
     R0 = A.shape[0]
     if not 0 < k <= I0:
@@ -108,7 +106,7 @@ def rule_topk(Q: jnp.ndarray, A: jnp.ndarray, sizes: jnp.ndarray,
     cons = jnp.pad(jnp.asarray(cons, jnp.int32), (0, pad_r),
                    constant_values=Ip)
     B, _ = Q.shape
-    cfg = resolve_config("rule_match", (B, Rp, Ip), tuning)
+    cfg, interpret = dispatch("rule_match", (B, Rp, Ip), tuning, interpret)
     bb = _fit(cfg.get("bb", 256), B)
     br = _fit(cfg.get("br", 256), Rp)
     bi = _fit(cfg.get("bi", 512), Ip)

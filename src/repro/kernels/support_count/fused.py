@@ -17,14 +17,19 @@ formulation; on MXU-rich devices the matmul usually wins.  Which variant
 runs where is exactly what :mod:`repro.kernels.autotune` measures.
 
 Tiling (HBM→VMEM):
-  grid = (M/bm, N/bn) — candidate tiles outermost, transaction tiles
-  innermost, so each [1, bm] output block is revisited only across the
-  sequential-innermost N axis (the revisit pattern TPU Pallas supports)
-  and Pallas' grid pipeline double-buffers the Tw/Cw block DMAs across
-  steps.  The word axis is carried whole per block: W = I/32 words is
-  small (a 4096-item universe is 128 lanes), so the [bn, W] and [bm, W]
-  blocks stay far below VMEM limits and the [bn, bm, W] popcount
-  intermediate is the working set that bounds bn·bm.
+  grid = (M/bm, N/bn) — candidate tiles outermost ("parallel"),
+  transaction tiles innermost ("arbitrary"), so each [1, bm] output
+  block is revisited only across the sequential-innermost N axis (the
+  revisit pattern TPU Pallas supports) and Pallas' grid pipeline
+  double-buffers the block DMAs across steps.  The word axis is carried
+  whole per block (W = I/32 words; a 4096-item universe is 128 lanes).
+  Candidate words arrive transposed, ``[W, bm]``, so each word is one
+  lane-dense row.  The body walks the block ``ROW_CHUNK`` transaction
+  rows at a time and, per chunk, the words one at a time: word column
+  ``[ROW_CHUNK, 1]`` AND word row ``[1, bm]``, popcount, add.  The live
+  set is a ``[ROW_CHUNK, bm]`` accumulator, never a ``[bn, bm, W]``
+  intermediate, so VMEM holds little beyond the double-buffered blocks
+  and the unrolled body does not grow with bn.
 
 Padding contract (shared with the MXU variant's ops wrapper): padded
 transaction rows are all-zero words (support only the empty itemset,
@@ -41,6 +46,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 WORD_BITS = 32
+# Transaction (query) rows per step of the body's row loop: one int32
+# sublane tile, so the accumulator of a chunk is bm/128 vregs.
+ROW_CHUNK = 8
 
 
 def pack_words(x: jnp.ndarray) -> jnp.ndarray:
@@ -58,26 +66,47 @@ def pack_words(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(bits * shifts, axis=2, dtype=jnp.uint32)
 
 
-def _popcount_dots(t: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
-    """[bn, W] x [bm, W] packed words -> [bn, bm] int32 AND-popcounts."""
-    inter = jax.lax.population_count(t[:, None, :] & c[None, :, :])
-    return jnp.sum(inter, axis=2).astype(jnp.int32)
+def as_word_lanes(words: jnp.ndarray) -> jnp.ndarray:
+    """Packed uint32 words [R, W] -> the kernels' operand layout: int32 bit
+    patterns (Mosaic reduces signed integers only; popcount ignores the
+    sign) with the word axis leading, ``[W, R]``."""
+    return jax.lax.bitcast_convert_type(words, jnp.int32).T
 
 
-def _kernel(t_ref, c_ref, sizes_ref, out_ref):
+def popcount_dots(t_ref, ct_ref, rows) -> jnp.ndarray:
+    """AND-popcounts of block rows ``rows`` (a ``pl.ds`` of ROW_CHUNK) of
+    the ``[bn, W]`` int32 word block against every column of the ``[W, bm]``
+    word block -> ``[ROW_CHUNK, bm]`` int32.  The word loop unrolls (W =
+    I/32 is static and small)."""
+    acc = jnp.zeros((ROW_CHUNK, ct_ref.shape[1]), jnp.int32)
+    for w in range(t_ref.shape[1]):
+        acc += jax.lax.population_count(
+            t_ref[rows, w:w + 1] & ct_ref[w:w + 1, :])
+    return acc
+
+
+def chunk_rows(r) -> pl.Slice:
+    return pl.ds(pl.multiple_of(r * ROW_CHUNK, ROW_CHUNK), ROW_CHUNK)
+
+
+def _kernel(t_ref, ct_ref, sizes_ref, out_ref):
     """Grid: (j, i) over (M-tiles, N-tiles); N innermost (out revisits)."""
     i = pl.program_id(1)
-    dots = _popcount_dots(t_ref[...], c_ref[...])          # [bn, bm]
-    hits = (dots == sizes_ref[...]).astype(jnp.int32)      # filter fused in
-    partial = jnp.sum(hits, axis=0, keepdims=True)         # [1, bm]
 
     @pl.when(i == 0)
-    def _init():
-        out_ref[...] = partial
+    def _zero_out():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(i != 0)
-    def _accum():
-        out_ref[...] += partial
+    sizes = sizes_ref[...]                                   # [1, bm]
+
+    def chunk(r, hits):                                      # filter fused in
+        dots = popcount_dots(t_ref, ct_ref, chunk_rows(r))
+        return hits + (dots == sizes).astype(jnp.int32)
+
+    hits = jax.lax.fori_loop(0, t_ref.shape[0] // ROW_CHUNK, chunk,
+                             jnp.zeros((ROW_CHUNK, sizes.shape[1]),
+                                       jnp.int32))
+    out_ref[...] += jnp.sum(hits, axis=0, keepdims=True)     # [1, bm]
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bm", "interpret"))
@@ -85,26 +114,29 @@ def support_count_fused_pallas(Tw: jnp.ndarray, Cw: jnp.ndarray,
                                sizes: jnp.ndarray, *, bn: int = 512,
                                bm: int = 256,
                                interpret: bool = False) -> jnp.ndarray:
-    """Tw: [N, W] uint32; Cw: [M, W] uint32; sizes: [1, M] i32 -> [1, M] i32."""
+    """Tw: [N, W] uint32; Cw: [M, W] uint32; sizes: [1, M] i32 -> [1, M] i32.
+    N must be a multiple of ROW_CHUNK."""
     N, W = Tw.shape
     M = Cw.shape[0]
     bn, bm = min(bn, N), min(bm, M)
-    assert N % bn == 0 and M % bm == 0, (Tw.shape, Cw.shape, (bn, bm))
+    assert N % bn == 0 and M % bm == 0 and bn % ROW_CHUNK == 0, \
+        (Tw.shape, Cw.shape, (bn, bm))
     grid = (M // bm, N // bn)
     return pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, W), lambda j, i: (i, 0)),
-            pl.BlockSpec((bm, W), lambda j, i: (j, 0)),
+            pl.BlockSpec((W, bm), lambda j, i: (0, j)),
             pl.BlockSpec((1, bm), lambda j, i: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, bm), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, M), jnp.int32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(Tw, Cw, sizes)
+    )(jax.lax.bitcast_convert_type(Tw, jnp.int32), as_word_lanes(Cw),
+      sizes.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bm", "interpret"))

@@ -38,16 +38,14 @@ from jax.experimental.pallas import tpu as pltpu
 def _kernel(a_ref, b_ref, out_ref):
     """Grid: (j, i) over (M-tiles, W-tiles); W innermost (out revisits)."""
     i = pl.program_id(1)
-    inter = jax.lax.population_count(a_ref[...] & b_ref[...])   # [bm, bw]
-    partial = jnp.sum(inter.astype(jnp.int32), axis=1)[None, :]  # [1, bm]
 
     @pl.when(i == 0)
-    def _init():
-        out_ref[...] = partial
+    def _zero_out():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(i != 0)
-    def _accum():
-        out_ref[...] += partial
+    # int32 bit patterns in (signed reductions only; popcount ignores sign)
+    inter = jax.lax.population_count(a_ref[...] & b_ref[...])   # [bm, bw]
+    out_ref[...] += jnp.sum(inter, axis=1)[None, :]              # [1, bm]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bw", "interpret"))
@@ -69,7 +67,8 @@ def intersect_count_pallas(A: jnp.ndarray, B: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((1, bm), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, M), jnp.int32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(A, B)
+    )(jax.lax.bitcast_convert_type(A, jnp.int32),
+      jax.lax.bitcast_convert_type(B, jnp.int32))

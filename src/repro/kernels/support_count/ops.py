@@ -16,10 +16,9 @@ the CI baselines hold the packed variant to *beating* the jitted ref.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
-from repro.kernels.autotune.cache import resolve_config
+from repro.kernels.autotune.cache import dispatch
 from repro.kernels.support_count.fused import support_count_fused
 from repro.kernels.support_count.intersect import intersect_count_pallas
 from repro.kernels.support_count.kernel import support_count_pallas
@@ -55,22 +54,20 @@ def support_count(T: jnp.ndarray, C: jnp.ndarray, *,
     roofline-seeded default config; a config ``dict`` or an
     ``AutotuneCache`` pins the choice (tests, the tuner, CI sweeps).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    N0, M0 = T.shape[0], C.shape[0]
+    M0 = C.shape[0]
     if M0 == 0:          # empty candidate level: nothing to count
         return jnp.zeros((0,), jnp.int32)
     T = _pad_to(_pad_to(T.astype(jnp.int8), 1, 128), 0, 8)
     C = _pad_to(_pad_to(C.astype(jnp.int8), 1, 128), 0, 128)
     N, I = T.shape
     M = C.shape[0]
-    cfg = resolve_config("support_count", (N, M, I), tuning)
+    cfg, interpret = dispatch("support_count", (N, M, I), tuning, interpret)
     bn = _fit(cfg.get("bn", 512), N)
     bm = _fit(cfg.get("bm", 256), M)
     if cfg.get("variant", "mxu") == "packed":
         out = support_count_fused(T, C, bn=bn, bm=bm, interpret=interpret)
     else:
-        sizes = C.astype(jnp.float32).sum(axis=1)[None, :]      # [1, M]
+        sizes = C.astype(jnp.int32).sum(axis=1)[None, :]        # [1, M]
         bi = _fit(cfg.get("bi", 512), I)
         out = support_count_pallas(T, C, sizes, bn=bn, bm=bm, bi=bi,
                                    interpret=interpret)
@@ -93,8 +90,6 @@ def intersect_count(A: jnp.ndarray, B: jnp.ndarray, *,
     autotune cache; ``False`` = roofline-seeded default config; a config
     ``dict`` or an ``AutotuneCache`` pins the choice.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if A.shape != B.shape:
         raise ValueError(f"slab shapes differ: {A.shape} vs {B.shape}")
     M0 = A.shape[0]
@@ -103,7 +98,7 @@ def intersect_count(A: jnp.ndarray, B: jnp.ndarray, *,
     A = _pad_to(_pad_to(A.astype(jnp.uint32), 1, 128), 0, 128)
     B = _pad_to(_pad_to(B.astype(jnp.uint32), 1, 128), 0, 128)
     M, W = A.shape
-    cfg = resolve_config("intersect_count", (M, W), tuning)
+    cfg, interpret = dispatch("intersect_count", (M, W), tuning, interpret)
     bm = _fit(cfg.get("bm", 256), M)
     bw = _fit(cfg.get("bw", 128), W)
     out = intersect_count_pallas(A, B, bm=bm, bw=bw, interpret=interpret)

@@ -24,7 +24,7 @@ import argparse
 from repro.kernels.autotune.cache import (DEFAULT_CACHE_PATH, AutotuneCache,
                                           device_kind)
 from repro.kernels.autotune.tuner import standard_shapes, tune_into
-from repro.launch.common import add_seed_arg
+from repro.launch.common import add_seed_arg, enable_compile_cache
 from repro.launch.tuning import TUNABLE_KERNELS
 
 
@@ -66,6 +66,7 @@ def main():
                     choices=list(TUNABLE_KERNELS),
                     help="restrict to one kernel (repeatable)")
     args = ap.parse_args()
+    enable_compile_cache()
     autotune(args.out, smoke=args.smoke, reps=args.reps,
              max_configs=args.max_configs, seed=args.seed,
              kernels=tuple(args.kernel) if args.kernel else TUNABLE_KERNELS)

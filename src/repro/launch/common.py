@@ -10,11 +10,14 @@ same name, default and help text.
 
 Each ``add_*`` helper attaches one coherent flag group to an existing
 parser; ``standard_parser()`` builds a parser with all of them for the
-entry points that want the full set.
+entry points that want the full set.  ``enable_compile_cache()`` is the
+one place the entry points (and ``chip_smoke.py``) turn on JAX's
+persistent compilation cache.
 """
 from __future__ import annotations
 
 import argparse
+import os
 
 from repro.core.hetero import HeterogeneityProfile
 from repro.runtime import POLICY_NAMES
@@ -27,6 +30,28 @@ PROFILES = {
     "homogeneous": lambda: HeterogeneityProfile.homogeneous(4, 200.0),
     "straggler": lambda: HeterogeneityProfile.straggler(8, 2, 4.0),
 }
+
+
+# The cache directory when JAX_COMPILATION_CACHE_DIR is unset: fixed, at
+# the root of the checkout (src/repro/launch/ -> three levels up), never
+# per run — a directory that moves never hits.
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache goes to
+    ``CHECKOUT_CACHE_DIR``.  Call before the first compilation."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR
 
 
 def add_corpus_args(ap: argparse.ArgumentParser, n_tx: int = 8192,

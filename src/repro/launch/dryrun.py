@@ -83,8 +83,7 @@ def lower_cell(arch: str, shape_name: str, mesh, profile: str = "tuned",
                    "grad_accum": opts.get("grad_accum", 1)},
     }
     t0 = time.time()
-    from repro.core.compat import mesh_context
-    ctx = mesh_context(mesh)          # ambient mesh for sequence_shard
+    ctx = jax.set_mesh(mesh)          # ambient mesh for sequence_shard
     ctx.__enter__()
 
     if shape.kind == "train":

@@ -7,7 +7,7 @@ import to get the 512 placeholder devices.
 """
 from __future__ import annotations
 
-from repro.core.compat import make_mesh
+from repro.distributed.meshes import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -18,9 +18,9 @@ features and picks one).  `--dataset sparse` generates a wide-universe
 low-frequency corpus consumed through the sparse CSR slab — the Eclat
 path then never materializes the dense bitmap.
 
-`--sharded` executes the distributed mining plane instead (shard_map over a
-device mesh; run with XLA_FLAGS=--xla_force_host_platform_device_count=8
-for a simulated 8-rank CPU mesh), and `--smoke` additionally runs the
+`--sharded` executes the distributed mining plane instead (shard_map over
+every visible device; on a CPU-only host, XLA_FLAGS=
+--xla_force_host_platform_device_count=8 simulates an 8-rank mesh), and `--smoke` additionally runs the
 single-device pipeline on the same data and asserts bit-identical itemsets
 and rules — the CI multi-device end-to-end check (run under both
 ``--policy static`` and ``--policy dynamic``: results must not depend on
@@ -45,7 +45,8 @@ import tempfile
 
 from repro.data.baskets import BasketConfig, generate_baskets, sparse_baskets
 from repro.data.sparse import SparseSlab
-from repro.launch.common import PROFILES, standard_parser
+from repro.launch.common import (PROFILES, enable_compile_cache,
+                                 standard_parser)
 from repro.pipeline import MarketBasketPipeline, PipelineConfig
 
 
@@ -220,11 +221,7 @@ def main():
                          "partition boundaries (exit code 3, checkpoint "
                          "kept — the CI kill-and-resume smoke)")
     args = ap.parse_args()
-    if args.sharded and "XLA_FLAGS" not in os.environ:
-        # default in a multi-device mesh for the CLI only — XLA reads this
-        # env at (lazy) backend initialization, which nothing in the import
-        # chain above triggers, so setting it here still takes effect
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    enable_compile_cache()
     mine(args.n_tx, args.n_items, args.min_support, args.min_confidence,
          args.profile, args.split, args.n_tiles, args.data_plane, args.seed,
          sharded=args.sharded, n_shards=args.n_shards, smoke=args.smoke,

@@ -30,7 +30,8 @@ import sys
 import numpy as np
 
 from repro.data.baskets import BasketConfig, generate_baskets
-from repro.launch.common import PROFILES, standard_parser
+from repro.launch.common import (PROFILES, enable_compile_cache,
+                                 standard_parser)
 from repro.pipeline import MarketBasketPipeline, PipelineConfig
 from repro.serving import (AsyncServer, Query, RecommendationEngine,
                            RuleIndex, ServingConfig, recommend_bruteforce)
@@ -214,6 +215,7 @@ def main():
                          "(with --async: pin async == closed-loop == oracle "
                          "under static AND dynamic policies)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.n_tx, args.n_items, args.queries = 2048, 64, 1000
         args.min_support = max(args.min_support, 0.03)

@@ -20,7 +20,8 @@ import sys
 import numpy as np
 
 from repro.data.baskets import BasketConfig, generate_baskets
-from repro.launch.common import PROFILES, standard_parser
+from repro.launch.common import (PROFILES, enable_compile_cache,
+                                 standard_parser)
 from repro.pipeline import MarketBasketPipeline
 from repro.serving import (Query, RecommendationEngine, RuleIndex,
                            ServingConfig, recommend_bruteforce)
@@ -158,6 +159,7 @@ def main():
                          "same window under static AND dynamic policies, "
                          "and that the live index serves the fresh rules")
     args = ap.parse_args()
+    enable_compile_cache()
     try:
         stream(args.n_tx, args.n_items, args.window, args.batch,
                args.batches, args.min_support, args.min_confidence,

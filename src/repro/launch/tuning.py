@@ -114,6 +114,50 @@ KERNEL_STEP_OVERHEAD_US = 15.0
 
 TUNABLE_KERNELS = ("support_count", "intersect_count", "rule_match")
 
+# Scoped VMEM one kernel may use on TPU v5e is 16 MiB by default; configs
+# whose estimated working set passes this budget leave the swept space,
+# because the compiler refuses them ("Ran out of memory in memory space
+# vmem").  The margin covers what the estimate leaves out.
+VMEM_BUDGET_BYTES = 12 * 2**20
+
+
+def _lane_pad(x: int) -> int:
+    return -(-int(x) // 128) * 128
+
+
+def _sublane_pad(x: int) -> int:
+    return -(-int(x) // 8) * 8
+
+
+def vmem_bytes(kernel: str, shape: Tuple[int, ...],
+               config: Dict[str, Any]) -> int:
+    """Estimated VMEM of one grid step: every block double-buffered by the
+    grid pipeline (minor dim padded to 128 lanes, a 1-row block to 8
+    sublanes), plus what the body keeps in VMEM — the MXU variants' int32
+    scratch accumulator and dot result, the intersect popcount temporary.
+    The packed variants walk their block 8 rows at a time in vregs."""
+    if kernel == "intersect_count":
+        tm, tw = config["bm"], config["bw"]
+        block = tm * _lane_pad(tw) * 4
+        return 2 * (2 * block + 8 * _lane_pad(tm) * 4) + block
+    n, m, i = shape
+    a, b = ("bn", "bm") if kernel == "support_count" else ("bb", "br")
+    tn, tm = config[a], config[b]
+    row = 8 * _lane_pad(tm) * 4                 # one [1, tm] i32/f32 block
+    if kernel == "support_count":
+        vecs, out = row, row                    # sizes; [1, bm] counts
+    else:
+        vecs, out = 2 * row, tn * _lane_pad(tm) * 4   # sizes, conf; scores
+    if config["variant"] == "mxu":
+        ti = config.get("bi", i)
+        ins = tn * _lane_pad(ti) + tm * _lane_pad(ti)          # int8
+        body = 2 * tn * _lane_pad(tm) * 4
+    else:
+        w = i // 32
+        ins = (tn * _lane_pad(w) + _sublane_pad(w) * _lane_pad(tm)) * 4
+        body = 0
+    return 2 * (ins + vecs + out) + body
+
 
 def _fit_tile(want: int, dim: int, floor: int = 1) -> int:
     """Largest power-of-two-shrunk tile <= want that divides dim."""
@@ -132,7 +176,9 @@ def kernel_candidates(kernel: str, shape: Tuple[int, ...]
     rule_match:      shape = (B, R, I) — queries, rule rows, items.
     Every candidate is a dict with a ``variant`` plus that variant's tile
     shape; all candidates compute bit-identical results (the fuzz harness
-    holds the tuner to that), so picking any of them is safe.
+    holds the tuner to that), so picking any of them is safe.  Candidates
+    over ``VMEM_BUDGET_BYTES`` (see :func:`vmem_bytes`) are left out; the
+    smallest tiles (8 rows, 128 lanes) always fit.
     """
     if kernel not in TUNABLE_KERNELS:
         raise ValueError(f"unknown tunable kernel {kernel!r} "
@@ -154,17 +200,17 @@ def kernel_candidates(kernel: str, shape: Tuple[int, ...]
             for ww in (512, 128, w):
                 add({"variant": "packed", "bm": _fit_tile(wm, m),
                      "bw": _fit_tile(ww, w)})
-        return cands
-
-    n, m, i = shape
-    a, b = ("bn", "bm") if kernel == "support_count" else ("bb", "br")
-    for wn in (512, 256, n):
-        for wm in (256, 128, m):
-            add({"variant": "mxu", a: _fit_tile(wn, n), b: _fit_tile(wm, m),
-                 "bi": _fit_tile(512, i)})
-            add({"variant": "packed", a: _fit_tile(wn, n),
-                 b: _fit_tile(wm, m)})
-    return cands
+    else:
+        n, m, i = shape
+        a, b = ("bn", "bm") if kernel == "support_count" else ("bb", "br")
+        for wn in (512, 256, n):
+            for wm in (256, 128, m):
+                add({"variant": "mxu", a: _fit_tile(wn, n),
+                     b: _fit_tile(wm, m), "bi": _fit_tile(512, i)})
+                add({"variant": "packed", a: _fit_tile(wn, n),
+                     b: _fit_tile(wm, m)})
+    return [c for c in cands
+            if vmem_bytes(kernel, shape, c) <= VMEM_BUDGET_BYTES]
 
 
 def estimate_cost_us(kernel: str, shape: Tuple[int, ...],

@@ -28,9 +28,8 @@ def sequence_shard(x: jnp.ndarray) -> jnp.ndarray:
     elementwise work) are TP-sharded instead of replicated.  GSPMD inserts
     the all-gather before attention and the reduce-scatter after the row
     matmuls.  No-op outside a mesh context or when dims don't divide."""
-    from repro.core.compat import get_abstract_mesh
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.axis_names or x.ndim < 3:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names or x.ndim < 3:
         return x
     names = mesh.axis_names
     batch_ax = tuple(a for a in ("pod", "data") if a in names)

@@ -36,9 +36,8 @@ def _expert_shard(x_t: jnp.ndarray) -> jnp.ndarray:
     """Sharding constraint for [E, B, C, d] (expert-major) dispatch tensors:
     E on the expert-parallel axis ("data"), matching the expert-weight
     sharding.  No-op outside a mesh context or when E doesn't divide."""
-    from repro.core.compat import get_abstract_mesh
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.axis_names or "data" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names or "data" not in mesh.axis_names:
         return x_t
     if x_t.shape[0] % mesh.shape["data"] != 0:
         return x_t

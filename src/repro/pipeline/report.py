@@ -98,6 +98,8 @@ class PipelineReport:
     execution: str = "simulated"  # "simulated" | "sharded" | "out_of_core"
     n_shards: int = 0             # mesh axis size (0 = single-device plane)
     shard_rows: List[int] = field(default_factory=list)  # final plan, per rank
+    # device id holding each rank's slab of the mined input, in rank order
+    shard_devices: List[int] = field(default_factory=list)
     replans: int = 0              # failure-triggered shard re-plans
     # out-of-core SON plane (execution == "out_of_core"):
     n_partitions: int = 0         # disk-resident chunks the corpus split into

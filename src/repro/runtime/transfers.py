@@ -66,14 +66,16 @@ class TransferMeter:
         self.syncs = 0
 
     # ------------------------------------------------------------------
-    def h2d(self, x: Any, dtype=None) -> jnp.ndarray:
+    def h2d(self, x: Any, dtype=None, sharding=None) -> jnp.ndarray:
         """Stage host data on device, counting the bytes moved.  A value
         that is already device-resident passes through uncounted — call
-        sites can route every input here without double-billing."""
+        sites can route every input here without double-billing.  With a
+        ``sharding``, each device receives only its own shard."""
         if isinstance(x, jax.Array):
             return x if dtype is None else x.astype(dtype)
         with jax.transfer_guard("allow"):
-            out = jnp.asarray(x, dtype=dtype)
+            out = (jnp.asarray(x, dtype=dtype) if sharding is None
+                   else jax.device_put(np.asarray(x, dtype=dtype), sharding))
         self.h2d_bytes += int(out.nbytes)
         return out
 

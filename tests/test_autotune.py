@@ -19,13 +19,15 @@ import jax.numpy as jnp
 
 from repro.core.hetero import HeterogeneityProfile
 from repro.core.scheduler import TaskSpec
-from repro.kernels.autotune.cache import (AutotuneCache, default_cache,
-                                          resolve_config, shape_bucket)
+from repro.kernels.autotune.cache import (LAST_DISPATCH, AutotuneCache,
+                                          default_cache, resolve_config,
+                                          shape_bucket)
 from repro.kernels.autotune.tuner import standard_shapes, tune, tune_into
 from repro.kernels.support_count.ops import support_count
 from repro.kernels.support_count.ref import support_count_ref
-from repro.launch.tuning import (TUNABLE_KERNELS, default_config,
-                                 kernel_candidates, shape_flops_bytes)
+from repro.launch.tuning import (TUNABLE_KERNELS, VMEM_BUDGET_BYTES,
+                                 default_config, kernel_candidates,
+                                 shape_flops_bytes, vmem_bytes)
 from repro.pipeline import MarketBasketPipeline, PipelineConfig
 from repro.runtime import (CostModelPolicy, MeasuredPhase, Runtime,
                            autotuned_costmodel)
@@ -119,6 +121,38 @@ def test_cold_and_corrupt_cache_degrade(tmp_path):
         np.asarray(support_count(jnp.asarray(T), jnp.asarray(C),
                                  tuning=corrupt)),
         np.asarray(support_count_ref(jnp.asarray(T), jnp.asarray(C))))
+
+
+def test_dispatch_records_config_source_and_interpret():
+    rng = np.random.default_rng(4)
+    T = jnp.asarray((rng.random((32, 64)) < 0.3).astype(np.uint8))
+    C = jnp.asarray((rng.random((8, 64)) < 0.1).astype(np.uint8))
+    pin = {"variant": "mxu", "bn": 8, "bm": 128, "bi": 128}
+    for tuning, source in ((pin, "pinned"), (False, "roofline"),
+                           (AutotuneCache(), "roofline"), (None, "cache")):
+        support_count(T, C, tuning=tuning)
+        rec = LAST_DISPATCH["support_count"]
+        assert rec["source"] == source and rec["shape"] == (32, 128, 128)
+        assert rec["interpret"] is True          # off-TPU default
+    assert LAST_DISPATCH["support_count"]["config"] == resolve_config(
+        "support_count", (32, 128, 128))
+
+
+@pytest.mark.parametrize("kernel,shape", [
+    ("support_count", (3128, 2176, 1024)),   # one 100k-tx row tile, k=2
+    ("rule_match", (512, 1920, 1024)),
+    ("intersect_count", (128, 3200)),
+])
+def test_candidates_fit_the_vmem_budget(kernel, shape):
+    cands = kernel_candidates(kernel, shape)
+    assert cands
+    assert all(vmem_bytes(kernel, shape, c) <= VMEM_BUDGET_BYTES
+               for c in cands)
+    # whole-array MXU tiles need ~26 MiB of scratch alone: never proposed
+    if kernel == "support_count":
+        whole = {"variant": "mxu", "bn": 3128, "bm": 2176, "bi": 512}
+        assert vmem_bytes(kernel, shape, whole) > VMEM_BUDGET_BYTES
+        assert whole not in cands
 
 
 def test_autotuned_costmodel_degrades_to_roofline():

@@ -22,7 +22,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core.mapreduce import MapReduceJob, run_sharded
 from repro.distributed.collectives import ring_all_gather, hierarchical_psum
 from repro.launch.mesh import make_test_mesh
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 mesh = make_test_mesh()  # (2 data, 4 model)
 out = {}
@@ -45,7 +45,7 @@ def body(xs):
     ref = jax.lax.all_gather(xs, "model").reshape(ring.shape)
     return (jnp.abs(ring - ref) < 1e-6).all()
 ok = shard_map(body, mesh=mesh, in_specs=(P("model", None),), out_specs=P(),
-               check_rep=False)(x)
+               check_vma=False)(x)
 out["ring_allgather_ok"] = bool(ok)
 
 # 3. hierarchical psum == flat psum on multipod mesh
@@ -56,7 +56,7 @@ def body2(ys):
     f = jax.lax.psum(ys, ("pod", "data"))
     return (jnp.abs(h - f) < 1e-6).all()
 ok2 = shard_map(body2, mesh=mesh2, in_specs=(P(("pod", "data")),),
-                out_specs=P(), check_rep=False)(y)
+                out_specs=P(), check_vma=False)(y)
 out["hier_psum_ok"] = bool(ok2)
 
 # 4. int8 quantized psum ~= f32 psum
@@ -68,7 +68,7 @@ def body3(gs):
     scale = jnp.max(jnp.abs(exact)) + 1e-9
     return (jnp.abs(approx - exact) / scale < 0.05).all()
 ok3 = shard_map(body3, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
-                check_rep=False)(g)
+                check_vma=False)(g)
 out["int8_psum_ok"] = bool(ok3)
 
 print("RESULT" + json.dumps(out))

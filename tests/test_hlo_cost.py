@@ -62,7 +62,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, json
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.launch.hlo_cost import analyze
-from repro.core.compat import make_mesh   # version-compat axis types
+from repro.distributed.meshes import make_mesh
 mesh = make_mesh((2, 4), ("data", "model"))
 def f(x, w):
     return jnp.sum(x @ w)

@@ -1,0 +1,103 @@
+"""Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e.
+
+The TPU compiler is installed with JAX and compiles for a chip that is
+described, not attached, so these tests catch what interpret mode cannot:
+Mosaic lowering errors (e.g. float accumulation of int8 operands, unsigned
+reductions) and kernels that overrun scoped VMEM.  Shapes are those of
+``chip_smoke.py`` at 100,000 transactions x 1,000 items; configs are the
+ones the ops wrappers resolve on a TPU (a cache miss, so the roofline
+default) or the ones the smoke pins.  Nothing runs and nothing is timed.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library, and every test worker imports
+this file.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.autotune.cache import default_cache
+from repro.kernels.rule_match.ops import rule_topk
+from repro.kernels.support_count.ops import intersect_count, support_count
+from repro.launch.tuning import VMEM_BUDGET_BYTES, default_config, vmem_bytes
+
+V5E_KIND = "TPU_v5_lite"        # jax device_kind "TPU v5 lite", cache-keyed
+
+# (kernel, padded shape the ops wrapper resolves at, pinned config or None
+# for the TPU cache-miss default)
+CASES = [
+    # Apriori k=2 round: one of 32 row tiles (3,125 rows -> 3,128) x the
+    # 2,145 candidates bucketed to 2,176
+    ("support_count", (3128, 2176, 1024), None),
+    # the smoke's kernels phase: 99,840 rows, 195 row blocks of 512
+    ("support_count", (99840, 384, 1024),
+     {"variant": "mxu", "bn": 512, "bm": 128, "bi": 512}),
+    ("support_count", (99840, 384, 1024),
+     {"variant": "packed", "bn": 512, "bm": 128}),
+    # Eclat round tile: 128 candidate tid-lists x 100,000 tx in 3,200 words
+    ("intersect_count", (128, 3200), None),
+    # serving: the 64-basket bucket against the mined index (816 rows)
+    ("rule_match", (64, 896, 1024), None),
+    ("rule_match", (512, 896, 1024),
+     {"variant": "mxu", "bb": 64, "br": 128, "bi": 512}),
+    ("rule_match", (512, 896, 1024),
+     {"variant": "packed", "bb": 64, "br": 128}),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _config(kernel, shape, pinned):
+    if pinned is not None:
+        return pinned
+    # what resolve_config does on the chip: the checked-in cache has no
+    # entry for this device kind, so the roofline default applies
+    assert default_cache().lookup(kernel, shape, device=V5E_KIND) is None
+    return default_config(kernel, shape)
+
+
+def _lowered(kernel, shape, cfg, one_chip):
+    S = lambda shp, dt: jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+    if kernel == "support_count":
+        n, m, i = shape
+        fn = lambda T, C: support_count(T, C, interpret=False, tuning=cfg)
+        return jax.jit(fn).lower(S((n, i), jnp.int8), S((m, i), jnp.int8))
+    if kernel == "intersect_count":
+        fn = lambda A, B: intersect_count(A, B, interpret=False, tuning=cfg)
+        return jax.jit(fn).lower(S(shape, jnp.uint32), S(shape, jnp.uint32))
+    b, r, i = shape
+    fn = lambda Q, A, sizes, conf, cons: rule_topk(
+        Q, A, sizes, conf, cons, k=5, n_items=1000, backend="pallas",
+        interpret=False, tuning=cfg)
+    return jax.jit(fn).lower(S((b, i), jnp.int8), S((r, i), jnp.int8),
+                             S((r,), jnp.float32), S((r,), jnp.float32),
+                             S((r,), jnp.int32))
+
+
+@pytest.mark.parametrize("kernel,shape,pinned", CASES,
+                         ids=[f"{k}-{'x'.join(map(str, s))}-"
+                              f"{(p or {}).get('variant', 'default')}"
+                              for k, s, p in CASES])
+def test_kernel_compiles_for_v5e(kernel, shape, pinned, one_chip):
+    cfg = _config(kernel, shape, pinned)
+    assert vmem_bytes(kernel, shape, cfg) <= VMEM_BUDGET_BYTES
+    hlo = _lowered(kernel, shape, cfg, one_chip).compile().as_text()
+    assert "tpu_custom_call" in hlo        # the Pallas kernel, not a fallback
