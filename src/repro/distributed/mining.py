@@ -327,12 +327,12 @@ class ShardedMiner:
                         n_tiles=self.profile.n)
 
         def execute(_asg, _costs):
-            result, rep = run_sharded(job, data, self.mesh, self.axis,
-                                      extra_args=extra_args)
+            result, _ = run_sharded(job, data, self.mesh, self.axis,
+                                    extra_args=extra_args)
             # the psum-reduced vector comes back host-side here, inside the
             # phase, so the round's single sync lands on this map record
             result = self.runtime.meter.d2h(result, dtype=np.int64)
-            return MeasuredPhase(result=result, wall_s=rep.makespan)
+            return MeasuredPhase(result=result)
 
         return self.runtime.run_phase(
             task, execute, tile_costs=costs,

@@ -2,7 +2,8 @@
 
 Composition (paper §V):
 
-  baskets ──pack──▶ bitmap T[n_tx, n_items]
+  baskets ──ingest──▶ bitmap T[n_tx, n_items] ──upload──▶ device tiles
+     │        (serial phases mba-ingest, mba-upload → Runtime.run_serial)
      │
      ├─ round k=1: item-frequency MapReduceJob (tiled over the profile)
      ├─ round k≥2: serial candidate generation  → Runtime.run_serial
@@ -198,6 +199,33 @@ class MarketBasketPipeline:
         """Returns (lane-padded bitmap, raw item count, raw tx count)."""
         return ingest_baskets(baskets)
 
+    def _stage(self, baskets: Baskets):
+        """The mine's first two serial phases.  ``mba-ingest`` validates
+        and packs the baskets into the lane-padded bitmap and cuts its row
+        tiles; ``mba-upload`` stages the tiles on the device once — every
+        round's map phase reuses them, so uploading per round would redo
+        the same transfers — and carries their bytes on its record.
+        Returns ``(device tiles, padded bitmap shape, raw item count, raw
+        tx count)``."""
+        cfg, rt = self.config, self.runtime
+        n_rows = (baskets.n_tx if isinstance(baskets, SparseSlab)
+                  else len(baskets))
+
+        def ingest():
+            T, n_items_raw, n_tx_raw = self._ingest(baskets)
+            return (T.shape, n_items_raw, n_tx_raw,
+                    uniform_tiles(T, cfg.n_tiles))
+
+        (shape, n_items_raw, n_tx_raw, host_tiles), _ = rt.run_serial(
+            "mba-ingest", cost=max(1.0, n_rows * cfg.serial_unit_cost),
+            fn=ingest, min_speed=cfg.serial_min_speed)
+        tiles, _ = rt.run_serial(
+            "mba-upload",
+            cost=max(1.0, float(sum(t.nbytes for t in host_tiles))),
+            fn=lambda: [rt.meter.h2d(t) for t in host_tiles],
+            min_speed=cfg.serial_min_speed)
+        return tiles, shape, n_items_raw, n_tx_raw
+
     def _map_round(self, job: MapReduceJob, tiles: List,
                    failures: Optional[List[FailureEvent]],
                    tile_flops: Optional[np.ndarray] = None,
@@ -252,12 +280,9 @@ class MarketBasketPipeline:
         rt.ledger.take_since(0)
         mark = rt.ledger.mark()
 
-        T, n_items_raw, n_tx_raw = self._ingest(baskets)
-        n_tx, n_items = T.shape                     # lane-padded (internal)
+        # n_items is the lane-padded (internal) width
+        tiles, (_, n_items), n_items_raw, n_tx_raw = self._stage(baskets)
         min_sup = cfg.abs_support(n_tx_raw)
-        # device-resident once: every round's map phase reuses these tiles,
-        # so uploading per round would redo the same host->device transfers
-        tiles = [rt.meter.h2d(t) for t in uniform_tiles(T, cfg.n_tiles)]
         tile_rows = np.array([t.shape[0] for t in tiles], dtype=np.float64)
 
         report = PipelineReport(
@@ -360,10 +385,9 @@ class MarketBasketPipeline:
         rt.ledger.take_since(0)
         mark = rt.ledger.mark()
 
-        T, n_items_raw, n_tx_raw = self._ingest(baskets)
-        n_tx, n_items = T.shape                     # lane-padded (internal)
+        # n_items is the lane-padded (internal) width
+        tiles, (_, n_items), n_items_raw, n_tx_raw = self._stage(baskets)
         min_sup = cfg.abs_support(n_tx_raw)
-        tiles = [rt.meter.h2d(t) for t in uniform_tiles(T, cfg.n_tiles)]
         tile_rows = np.array([t.shape[0] for t in tiles], dtype=np.float64)
 
         report = PipelineReport(

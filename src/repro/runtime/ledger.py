@@ -20,6 +20,9 @@ Semantics, identical for every plane:
 * ``switches`` / ``reissued`` — planner moves (policy rebalancing, shard
   re-plans) plus execution moves (failure re-planning) for this phase
   only; the scheduler keeps its own lifetime counter.
+* ``host_time_s`` — measured host seconds of the phase's work (its
+  ``fn`` / ``execute``), timed by the Runtime under the phase's profiler
+  span; ``lowerings`` / ``compile_s`` — the JAX compiles in the phase.
 * ``constraint_violated`` — ``assign_serial`` could not satisfy the
   task's ``min_speed`` and fell back to the fastest core (surfaced, never
   silent).
@@ -46,7 +49,8 @@ class PhaseRecord:
     #                               bytes | roofline | autotune
     cost: float = 0.0             # work units the scheduler planned for
     sim_time_s: float = 0.0       # serial run time / map makespan (modeled)
-    host_time_s: float = 0.0      # measured host wall (0 = not measured)
+    host_time_s: float = 0.0      # host wall of the phase's work, timed by
+    #                               the Runtime (0 = a modelled-only phase)
     energy_j: float = 0.0
     switches: int = 0
     reissued: int = 0
@@ -64,6 +68,12 @@ class PhaseRecord:
     h2d_bytes: int = 0
     d2h_bytes: int = 0
     syncs: int = 0
+    # JAX compiles attributed to this phase (``runtime.compiles``; work
+    # between phases lands on the next one, as transfers do): executables
+    # lowered, and seconds spent tracing, lowering and compiling.  A warm,
+    # repeated phase reads 0 lowerings.
+    lowerings: int = 0
+    compile_s: float = 0.0
 
 
 @dataclass

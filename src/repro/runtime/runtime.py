@@ -16,6 +16,16 @@ returning a :class:`MeasuredPhase`); the runtime supplies *scheduling*
 Anything the executor does not measure is modeled from the plan: busy
 seconds default to ``load / believed_speed`` and the makespan to their
 maximum, so simulated, sharded and serving phases share one time axis.
+
+Measurement happens here too, once for every plane: each phase's work
+(a serial ``fn`` or a parallel ``execute``) runs inside a
+``jax.profiler.TraceAnnotation`` named by :func:`span_name` and is timed
+with ``perf_counter`` into ``PhaseRecord.host_time_s``, so the ledger and
+a profiler trace share one set of phase names and one clock.  The spans
+are inert when no profiler runs.  Each record also carries the
+host/device transfers (:class:`~repro.runtime.transfers.TransferMeter`)
+and the JAX compiles (:data:`~repro.runtime.compiles.COMPILES`) since the
+previous phase ended.
 """
 from __future__ import annotations
 
@@ -24,10 +34,12 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, List, Optional, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.hetero import HeterogeneityProfile
 from repro.core.power import PowerModel
 from repro.core.scheduler import Assignment, MBScheduler, TaskSpec
+from repro.runtime.compiles import COMPILES
 from repro.runtime.ledger import ExecLedger, PhaseRecord
 from repro.runtime.policies import SwitchingPolicy, resolve_policy
 from repro.runtime.transfers import TransferMeter
@@ -47,13 +59,29 @@ class MeasuredPhase:
     tiles_done: Optional[List[int]] = None
     work_done: Optional[np.ndarray] = None  # [n] executed work units (feeds
     #                                         DynamicPolicy's EWMA loop)
-    wall_s: float = 0.0                    # measured host wall
     # transfers the executor measured *outside* the runtime's meter (e.g.
     # a shard_map barrier counted as one sync); added on top of the meter
     # delta when the phase is recorded
     h2d_bytes: int = 0
     d2h_bytes: int = 0
     syncs: int = 0
+
+
+def span_name(name: str) -> str:
+    """The profiler span of a phase: its record name without a trailing
+    per-step counter (``serve-score-17`` -> ``serve-score``), so a span
+    names the kind of work.  Levels stay (``mba-candgen-k3``,
+    ``mba-round2-support``): k is a lattice level, not a counter."""
+    head, _, tail = name.rpartition("-")
+    return head if head and tail.isdigit() else name
+
+
+def _timed(name: str, fn: Callable[[], Any]):
+    """Run ``fn`` under its phase's span; ``(result, host seconds)``."""
+    with TraceAnnotation(span_name(name)):
+        t0 = time.perf_counter()
+        result = fn()
+        return result, time.perf_counter() - t0
 
 
 def resolve_power(power: Union[str, PowerModel, None],
@@ -90,11 +118,16 @@ class Runtime:
         # so inter-phase staging (tile uploads) lands on its consumer
         self.meter = meter if meter is not None else TransferMeter()
         self._transfer_mark = self.meter.stats()
+        self._compile_mark = COMPILES.stats()
 
-    def _take_transfers(self):
-        delta = self.meter.since(self._transfer_mark)
+    def _take_counters(self):
+        """Transfers and compiles since the previous phase ended."""
+        xfer = self.meter.since(self._transfer_mark)
         self._transfer_mark = self.meter.stats()
-        return delta
+        compiles = COMPILES.stats()
+        delta = compiles - self._compile_mark
+        self._compile_mark = compiles
+        return xfer, delta
 
     @property
     def split(self) -> str:
@@ -108,7 +141,8 @@ class Runtime:
                    fn: Optional[Callable[[], Any]] = None,
                    device: Optional[int] = None,
                    min_speed: float = 0.0,
-                   kind: str = "serial"):
+                   kind: str = "serial",
+                   assignment: Optional[Assignment] = None):
         """Model (and optionally execute) a single-threaded phase.
 
         ``fn`` runs on the host and its wall time is recorded; ``device``
@@ -116,23 +150,24 @@ class Runtime:
         ``kind`` stamps the ledger record — serial-shaped work that is not
         a plain driver phase (the async serving plane's SLO sheds) stays
         distinguishable without a second accounting path.  Returns
-        ``(fn result or None, PhaseRecord)``.
+        ``(fn result or None, PhaseRecord)``.  ``fn`` runs under the
+        phase's profiler span.  ``assignment`` pins a plan the caller made
+        with ``scheduler.assign_serial`` (as ``run_phase``'s does), for a
+        caller whose ``fn`` needs the phase's modelled duration.
         """
-        task = TaskSpec(name, cost, parallel=False, min_speed=min_speed)
-        asg = self.scheduler.assign_serial(task, device=device)
+        asg = assignment
+        if asg is None:
+            task = TaskSpec(name, cost, parallel=False, min_speed=min_speed)
+            asg = self.scheduler.assign_serial(task, device=device)
         dev = asg.serial_device
         sim_t = float(asg.est_finish[dev])
-        result, host_t = None, 0.0
-        if fn is not None:
-            t0 = time.perf_counter()
-            result = fn()
-            host_t = time.perf_counter() - t0
+        result, host_t = (None, 0.0) if fn is None else _timed(name, fn)
         energy = 0.0
         busy = np.zeros(self.profile.n)
         busy[dev] = sim_t
         if self.power is not None:
             energy = self.power.energy(busy, sim_t, gated=asg.gated)
-        xfer = self._take_transfers()
+        xfer, compiles = self._take_counters()
         rec = self.ledger.add(PhaseRecord(
             name=name, kind=kind, policy=self.policy.name,
             cost_source=getattr(self.policy, "cost_source", "bytes"),
@@ -141,7 +176,8 @@ class Runtime:
             busy_s=[float(b) for b in busy], gated=list(asg.gated),
             device=dev, constraint_violated=asg.constraint_violated,
             h2d_bytes=xfer.h2d_bytes, d2h_bytes=xfer.d2h_bytes,
-            syncs=xfer.syncs))
+            syncs=xfer.syncs, lowerings=compiles.lowerings,
+            compile_s=compiles.compile_s))
         return result, rec
 
     # ------------------------------------------------------------------
@@ -176,7 +212,7 @@ class Runtime:
         else:
             asg, plan_sw, plan_re = assignment, 0, 0
 
-        measured = execute(asg, costs)
+        measured, host_t = _timed(task.name, lambda: execute(asg, costs))
 
         # model whatever the executor did not measure
         load = np.array([costs[ts].sum() if ts else 0.0
@@ -212,12 +248,12 @@ class Runtime:
                     energy += (self.power.p_gated[d]
                                - self.power.p_idle[d]) * tail
 
-        xfer = self._take_transfers()
+        xfer, compiles = self._take_counters()
         rec = self.ledger.add(PhaseRecord(
             name=task.name, kind="map", policy=self.policy.name,
             cost_source=getattr(self.policy, "cost_source", "bytes"),
             cost=task.cost, sim_time_s=makespan,
-            host_time_s=measured.wall_s, energy_j=energy,
+            host_time_s=host_t, energy_j=energy,
             switches=switches, reissued=reissued,
             busy_s=[float(b) for b in busy], gated=gated,
             n_tiles=n_tiles,
@@ -227,7 +263,8 @@ class Runtime:
             failed_devices=list(measured.failed_devices),
             h2d_bytes=xfer.h2d_bytes + measured.h2d_bytes,
             d2h_bytes=xfer.d2h_bytes + measured.d2h_bytes,
-            syncs=xfer.syncs + measured.syncs))
+            syncs=xfer.syncs + measured.syncs,
+            lowerings=compiles.lowerings, compile_s=compiles.compile_s))
         return measured.result, rec
 
     # ------------------------------------------------------------------

@@ -95,7 +95,7 @@ class Handle:
     """
 
     __slots__ = ("rid", "query", "arrival_s", "bits", "key", "status",
-                 "done_s", "_result", "_event", "_delivered")
+                 "taken_s", "done_s", "_result", "_event", "_delivered")
 
     def __init__(self, rid: int, query: Query, arrival_s: float,
                  bits: np.ndarray, key: bytes):
@@ -105,7 +105,10 @@ class Handle:
         self.bits = bits            # canonical 0/1 vector (validated early)
         self.key = key              # cache key for the canonical basket
         self.status = "pending"
-        self.done_s = 0.0           # completion instant on the server clock
+        # instants on the server clock: taken from the queue by a drain
+        # step (queue wait = taken_s - arrival_s), then completed
+        self.taken_s = 0.0
+        self.done_s = 0.0
         self._result: Optional[Recommendation] = None
         self._event = threading.Event()
         self._delivered = False     # consumed by drain() exactly once
@@ -125,7 +128,9 @@ class Handle:
 
     @property
     def latency_s(self) -> float:
-        """Completion minus arrival on the server clock (0 while pending)."""
+        """Completion minus arrival on the server clock (0 while pending);
+        queue wait ``taken_s - arrival_s`` plus service ``done_s -
+        taken_s``."""
         return self.done_s - self.arrival_s if self.done() else 0.0
 
     def result(self, timeout: Optional[float] = None) -> Recommendation:
@@ -173,12 +178,15 @@ class RequestQueue:
             return self._q[0].arrival_s if self._q else None
 
     def take_ready(self, now: float, limit: int) -> List[Handle]:
-        """Pop up to ``limit`` head requests whose arrival is ``<= now``."""
+        """Pop up to ``limit`` head requests whose arrival is ``<= now``,
+        stamping each one's ``taken_s`` with ``now``."""
         out: List[Handle] = []
         with self._cond:
             while self._q and len(out) < limit \
                     and self._q[0].arrival_s <= now:
-                out.append(self._q.popleft())
+                h = self._q.popleft()
+                h.taken_s = now
+                out.append(h)
         return out
 
     def wait_nonempty(self, timeout: float) -> bool:

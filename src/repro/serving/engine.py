@@ -45,6 +45,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.hetero import HeterogeneityProfile
 from repro.core.power import PowerModel
@@ -267,20 +268,29 @@ class RecommendationEngine:
 
     def _score_batch(self, rows: List[np.ndarray],
                      bucket: int) -> List[Recommendation]:
-        """Run the rule-match data plane on a pad-to-bucket query block."""
+        """Run the rule-match data plane on a pad-to-bucket query block.
+
+        Each step is a profiler span (inert without a profiler):
+        ``serve-pad`` builds the block, ``serve-dispatch`` launches the
+        kernel, ``serve-readback`` waits for and copies its result,
+        ``serve-decode`` builds the per-request lists."""
         cfg = self.config
-        Q = np.zeros((bucket, self.index.n_items_padded), dtype=np.uint8)
-        for r, bits in enumerate(rows):
-            Q[r, :self.index.n_items] = bits
-        items, scores = rule_topk(
-            Q, self._dev["ante"], self._dev["sizes"], self._dev["conf"],
-            self._dev["cons"], k=cfg.k, n_items=self.index.n_items,
-            backend=self.backend, interpret=cfg.interpret,
-            tuning=None if cfg.autotune else False)
-        items = np.asarray(items)
-        scores = np.asarray(scores)
-        return [[(int(i), float(s)) for i, s in zip(items[r], scores[r])
-                 if s > 0.0] for r in range(len(rows))]
+        with TraceAnnotation("serve-pad"):
+            Q = np.zeros((bucket, self.index.n_items_padded), dtype=np.uint8)
+            for r, bits in enumerate(rows):
+                Q[r, :self.index.n_items] = bits
+        with TraceAnnotation("serve-dispatch"):
+            items, scores = rule_topk(
+                Q, self._dev["ante"], self._dev["sizes"], self._dev["conf"],
+                self._dev["cons"], k=cfg.k, n_items=self.index.n_items,
+                backend=self.backend, interpret=cfg.interpret,
+                tuning=None if cfg.autotune else False)
+        with TraceAnnotation("serve-readback"):
+            items = np.asarray(items)
+            scores = np.asarray(scores)
+        with TraceAnnotation("serve-decode"):
+            return [[(int(i), float(s)) for i, s in zip(items[r], scores[r])
+                     if s > 0.0] for r in range(len(rows))]
 
     # ------------------------------------------------------------------
     # the async surface: submit / poll / drain on a persistent open loop

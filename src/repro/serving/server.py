@@ -48,6 +48,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.scheduler import TaskSpec
 from repro.runtime import ExecLedger, LedgerTotals, MeasuredPhase
@@ -308,37 +309,49 @@ class AsyncServer:
         t = now                       # simulated-axis step time
         if shed:
             # triage is real serial work: one phase covering this step's
-            # rejects, priced through the scheduler like any admission
-            _, rec = rt.run_serial(
+            # rejects, priced through the scheduler like any admission.
+            # Planned first: on the modelled axis a reject completes at
+            # the end of the triage phase, which its fn must know
+            triage = TaskSpec(
                 f"{self.name}-shed-{step_i}",
                 cost=max(1.0, len(shed) * eng.config.admission_unit_cost),
-                min_speed=eng.config.admission_min_speed, kind="shed")
-            t += rec.sim_time_s
-            # completion instants live in the clock's own domain: the
-            # modeled axis when simulating, host wall when live
-            t_shed = t if sim else self.clock.now()
-            for h in shed:
-                h._finish("shed", None, t_shed)
+                parallel=False, min_speed=eng.config.admission_min_speed)
+            asg = rt.scheduler.assign_serial(triage)
+            t += float(asg.est_finish[asg.serial_device])
+
+            def finish_shed(t_sim=t):
+                # completion instants live in the clock's own domain: the
+                # modeled axis when simulating, host wall when live
+                t_shed = t_sim if sim else self.clock.now()
+                for h in shed:
+                    h._finish("shed", None, t_shed)
+
+            rt.run_serial(triage.name, triage.cost, fn=finish_shed,
+                          kind="shed", assignment=asg)
 
         stats = StepStats(t_start=now, t_done=t, n_shed=len(shed))
         if admit:
             t_wall0 = time.perf_counter()
+            # the bucket prices the admission phase, so it is picked first
             bucket = self.ladder.pick(len(admit))
             miss: List[Handle] = []
-            hits = 0
-            for h in admit:
-                cached = eng.cache.get(h.key)
-                if cached is not None:
-                    h._result = cached        # finished below at t_done
-                    hits += 1
-                else:
-                    miss.append(h)
+
+            def lookup():
+                hits = 0
+                for h in admit:
+                    cached = eng.cache.get(h.key)
+                    if cached is not None:
+                        h._result = cached    # finished below at t_done
+                        hits += 1
+                    else:
+                        miss.append(h)
+                return hits
 
             # serial admission/dispatch: best core runs, the rest gate off
-            _, adm = rt.run_serial(
+            hits, adm = rt.run_serial(
                 f"{self.name}-admit-{step_i}",
                 cost=max(1.0, bucket * eng.config.admission_unit_cost),
-                min_speed=eng.config.admission_min_speed)
+                fn=lookup, min_speed=eng.config.admission_min_speed)
             t += adm.sim_time_s
 
             if miss:
@@ -350,11 +363,8 @@ class AsyncServer:
                                 n_tiles=bucket, family="serve-score")
 
                 def execute(_asg, _costs, rows=miss, b=bucket):
-                    t0 = time.perf_counter()
-                    recs = eng._score_batch([h.bits for h in rows], b)
-                    # measured step wall -> policy feedback + SLO EWMA
-                    return MeasuredPhase(result=recs,
-                                         wall_s=time.perf_counter() - t0)
+                    return MeasuredPhase(
+                        result=eng._score_batch([h.bits for h in rows], b))
 
                 # each core spun up away from the admission core is a switch
                 recs, score_rec = rt.run_phase(task, execute,
@@ -408,14 +418,20 @@ class AsyncServer:
         self._thread = None
 
     def _drain_loop(self) -> None:
+        # profiler spans (inert without a profiler): an idle chip is
+        # either waiting here for requests or inside a step
+        wait_span, step_span = f"{self.name}-wait", f"{self.name}-step"
         while not self._stop.is_set():
-            if not self.queue.wait_nonempty(timeout=0.02):
-                continue
-            # bounded coalescing wait: let a concurrent burst fill the
-            # slots, but never make a lone request wait for a full bucket
-            if self.coalesce_wait_s > 0 and len(self.queue) < self.slots:
-                self.queue.wait_depth(self.slots, self.coalesce_wait_s)
-            self.step()
+            with TraceAnnotation(wait_span):
+                if not self.queue.wait_nonempty(timeout=0.02):
+                    continue
+                # bounded coalescing wait: let a concurrent burst fill the
+                # slots, but never make a lone request wait for a full
+                # bucket
+                if self.coalesce_wait_s > 0 and len(self.queue) < self.slots:
+                    self.queue.wait_depth(self.slots, self.coalesce_wait_s)
+            with TraceAnnotation(step_span):
+                self.step()
 
     def __enter__(self) -> "AsyncServer":
         return self.start()

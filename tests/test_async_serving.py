@@ -316,3 +316,90 @@ def test_threaded_wall_clock_mode(mined):
     assert rep.clock == "wall"
     assert rep.n_completed == 12 and rep.n_shed == 0
     assert rep.p99_latency_s > 0
+
+
+# ---------------------------------------------------------------------------
+# measurement: queue wait vs service, every phase timed, profiler spans
+# ---------------------------------------------------------------------------
+
+def test_queue_wait_and_service_split_on_the_virtual_clock(mined):
+    T, res = mined
+    server = AsyncServer(make_engine(res, buckets=(1, 8)), warm=False)
+    handles = [server.submit(q, arrival_s=0.001 * i)
+               for i, q in enumerate(queries_of(T, 12))]
+    server.drain()
+    assert all(h.arrival_s <= h.taken_s <= h.done_s for h in handles)
+    # a request that arrived mid-step waited for the next one
+    assert any(h.taken_s > h.arrival_s for h in handles)
+    assert all(h.latency_s == pytest.approx(
+        (h.taken_s - h.arrival_s) + (h.done_s - h.taken_s))
+        for h in handles)
+
+
+def test_queue_wait_and_service_split_on_the_wall_clock(mined):
+    T, res = mined
+    with AsyncServer(make_engine(res)) as server:
+        handles = [server.submit(q) for q in queries_of(T, 12)]
+        for h in handles:
+            h.result(timeout=30.0)
+    assert server.clock.domain == "wall"
+    assert all(h.arrival_s <= h.taken_s <= h.done_s for h in handles)
+
+
+def test_admit_and_shed_phases_time_their_work(mined):
+    T, res = mined
+    engine = make_engine(res, slo_ms=1000.0)
+    server = AsyncServer(engine)
+    for b in server.ladder.buckets:
+        server.ladder.observe(b, 0.5)
+    qs = queries_of(T, 2)
+    late = server.submit(qs[0], arrival_s=0.0)
+    fresh = server.submit(qs[1], arrival_s=0.8)
+    server.clock.advance(0.8)
+    server.drain()
+    assert late.status == "shed" and fresh.status == "done"
+    phases = server.take_report().ledger.phases
+    assert [p.name for p in phases] == ["serve-shed-0", "serve-admit-0",
+                                        "serve-score-0"]
+    assert all(p.host_time_s > 0 for p in phases)
+    # on the modelled axis a reject completes at the end of its triage
+    shed = phases[0]
+    assert late.taken_s == 0.8
+    assert late.done_s == pytest.approx(0.8 + shed.sim_time_s)
+    assert fresh.done_s == pytest.approx(
+        0.8 + sum(p.sim_time_s for p in phases))
+
+
+def _bench_trace_module():
+    """The benchmark's trace reduction (``bench/mba_bench/trace.py``)."""
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from mba_bench import trace
+    return trace
+
+
+def test_profiler_trace_holds_the_phase_spans(mined, tmp_path):
+    import jax
+    trace = _bench_trace_module()
+    T, res = mined
+    pipe = MarketBasketPipeline(config=PipelineConfig(
+        min_support=0.05, min_confidence=0.5, n_tiles=4))
+    engine = make_engine(res)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        pipe.run(T)
+        with AsyncServer(engine) as server:
+            for h in [server.submit(q) for q in queries_of(T, 4)]:
+                h.result(timeout=30.0)
+    finally:
+        jax.profiler.stop_trace()
+    tr = trace.load(trace.find_xplane(str(tmp_path)))
+    names = {e.name for e in tr.host_events}
+    assert {"mba-ingest", "mba-upload", "mba-round2-support",
+            "mba-candgen-k2", "mba-rules", "serve-wait", "serve-step",
+            "serve-admit", "serve-score", "serve-pad", "serve-dispatch",
+            "serve-readback", "serve-decode"} <= names, sorted(names)

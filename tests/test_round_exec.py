@@ -44,10 +44,17 @@ def test_two_round_mine_transfer_ledger_is_exact():
     m_cap = rounds[1].m_padded
     f2 = rounds[1].n_frequent
 
-    # round 1: the one-time tile upload stages here (256 uint8 rows), and
-    # the single readback is the padded int64 item-count vector
+    # the one-time tile upload (256 uint8 rows) is its own phase; ingest
+    # before it moves nothing across the boundary
+    ing, up = by_name["mba-ingest"], by_name["mba-upload"]
+    assert ing.h2d_bytes == ing.d2h_bytes == ing.syncs == 0
+    assert up.h2d_bytes == 256 * n_items_pad
+    assert up.d2h_bytes == 0 and up.syncs == 0
+
+    # round 1: no upload; the single readback is the padded int64
+    # item-count vector
     r1 = by_name["mba-round1-item-counts"]
-    assert r1.h2d_bytes == 256 * n_items_pad
+    assert r1.h2d_bytes == 0
     assert r1.d2h_bytes == n_items_pad * 8
     assert r1.syncs == 1
 
@@ -70,7 +77,7 @@ def test_two_round_mine_transfer_ledger_is_exact():
     assert ru.d2h_bytes == f2 * 2 * 4
     assert ru.syncs == 1
 
-    assert led.total_h2d_bytes == r1.h2d_bytes + cg.h2d_bytes
+    assert led.total_h2d_bytes == up.h2d_bytes + cg.h2d_bytes
     assert led.total_d2h_bytes == (r1.d2h_bytes + r2.d2h_bytes
                                    + ru.d2h_bytes)
     assert led.total_syncs == 3

@@ -327,3 +327,77 @@ def test_planes_share_report_semantics():
     res2 = MarketBasketPipeline(
         config=PipelineConfig(min_support=0.05, n_tiles=4)).run(T)
     assert res2.report.ledger.n_phases == len(res2.report.ledger.phases)
+
+
+# ---------------------------------------------------------------------------
+# measurement at the chokepoint: every phase timed, spanned and counted
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name,span", [
+    ("serve-score-17", "serve-score"), ("serve-admit-0", "serve-admit"),
+    ("mba-round2-support", "mba-round2-support"),
+    ("mba-candgen-k3", "mba-candgen-k3"), ("mba-ingest", "mba-ingest"),
+    ("42", "42")])
+def test_span_name_drops_only_a_trailing_step_counter(name, span):
+    from repro.runtime.runtime import span_name
+    assert span_name(name) == span
+
+
+@pytest.mark.parametrize("round_execution", ["pipelined", "per_tile"])
+def test_every_mine_phase_is_timed_and_tiles_land_on_upload(
+        round_execution):
+    from repro.data.baskets import BasketConfig, generate_baskets
+    from repro.pipeline import MarketBasketPipeline, PipelineConfig
+    T = generate_baskets(BasketConfig(n_tx=256, n_items=24, seed=3))
+    res = MarketBasketPipeline(config=PipelineConfig(
+        min_support=0.05, n_tiles=4, data_plane="ref",
+        round_execution=round_execution)).run(T)
+    phases = res.report.ledger.phases
+    names = [p.name for p in phases]
+    assert names[:3] == ["mba-ingest", "mba-upload",
+                         "mba-round1-item-counts"]
+    assert "mba-round2-support" in names and names[-1] == "mba-rules"
+    assert all(p.host_time_s > 0 for p in phases), \
+        [(p.name, p.host_time_s) for p in phases if p.host_time_s <= 0]
+    # the phases lie inside the mine's own wall
+    assert sum(p.host_time_s for p in phases) <= res.report.wall_time_s
+    # the one-time tile upload: 4 tiles of 64 rows x 128 lane-padded items
+    assert phases[1].h2d_bytes == 256 * 128
+    assert phases[0].h2d_bytes == phases[2].h2d_bytes == 0
+    # ingest and upload are priced like every other serial phase
+    assert all(p.kind == "serial" and p.sim_time_s > 0 and p.energy_j > 0
+               for p in phases[:2])
+
+
+def test_phase_that_lowers_a_fresh_shape_counts_it_and_a_repeat_does_not():
+    import jax
+    import jax.numpy as jnp
+    rt = Runtime(HeterogeneityProfile.paper(), power="none")
+    f = jax.jit(lambda x: x * 3 + 1)
+    x = jnp.ones(77, jnp.float32)
+    _, first = rt.run_serial("lower", cost=1.0, fn=lambda: f(x))
+    _, repeat = rt.run_serial("lower", cost=1.0, fn=lambda: f(x))
+    assert first.lowerings >= 1 and first.compile_s > 0
+    assert repeat.lowerings == 0 and repeat.compile_s == 0.0
+
+    # a map phase counts the same way, and between-phase work lands on
+    # the next phase, as transfers do
+    g = jax.jit(lambda x: x - 5)
+    g(jnp.ones(78, jnp.float32))             # between phases
+    task = TaskSpec("map", 4.0, parallel=True, n_tiles=4)
+    _, rec = rt.run_phase(task, modeled_executor())
+    assert rec.lowerings >= 1 and rec.host_time_s > 0
+    _, rec = rt.run_phase(task, modeled_executor())
+    assert rec.lowerings == 0
+
+
+def test_steady_mine_lowers_nothing():
+    from repro.data.baskets import BasketConfig, generate_baskets
+    from repro.pipeline import MarketBasketPipeline, PipelineConfig
+    T = generate_baskets(BasketConfig(n_tx=256, n_items=24, seed=3))
+    pipe = MarketBasketPipeline(config=PipelineConfig(
+        min_support=0.05, n_tiles=4, data_plane="ref"))
+    # the first mine compiles its lattice; the second may still zero the
+    # pooled count slabs the first left behind, a first op per slab shape
+    runs = [pipe.run(T) for _ in range(3)]
+    assert sum(p.lowerings for p in runs[2].report.ledger.phases) == 0

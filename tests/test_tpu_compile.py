@@ -101,3 +101,21 @@ def test_kernel_compiles_for_v5e(kernel, shape, pinned, one_chip):
     assert vmem_bytes(kernel, shape, cfg) <= VMEM_BUDGET_BYTES
     hlo = _lowered(kernel, shape, cfg, one_chip).compile().as_text()
     assert "tpu_custom_call" in hlo        # the Pallas kernel, not a fallback
+
+
+# the benchmark's readers and its breakdown key on these device-op names
+# (`support_count_roofline` sums the ops named `support_count*`)
+NAMED = [
+    ("support_count", (99840, 384, 1024),
+     {"variant": "packed", "bn": 512, "bm": 128}, "support_count_fused_pallas"),
+    ("rule_match", (512, 896, 1024),
+     {"variant": "packed", "bb": 64, "br": 128}, "rule_scores_fused_pallas"),
+]
+
+
+@pytest.mark.parametrize("kernel,shape,pinned,name", NAMED,
+                         ids=[n for *_, n in NAMED])
+def test_packed_kernel_keeps_its_name_in_v5e_hlo(kernel, shape, pinned, name,
+                                                 one_chip):
+    hlo = _lowered(kernel, shape, pinned, one_chip).compile().as_text()
+    assert name in hlo
