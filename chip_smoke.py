@@ -10,7 +10,9 @@ Every phase goes through the entry points a user calls (``make_miner``,
 from ``--seed`` by ``data.baskets.generate_baskets``:
 
   kernels  each main-path Pallas kernel, both variants, at tiles pinned so
-           that every call runs several row blocks; equal to the jnp refs.
+           that every call runs several row blocks, and support_count at
+           the checked-in cache's config for two row counts off the
+           sweep's lattice; equal to the jnp refs.
   apriori  ``data_plane="auto"`` must resolve to compiled Pallas; supports
            and rules bit-identical to the same mine with ``data_plane="ref"``.
   eclat    the same for ``algorithm="eclat"`` (the ``intersect_count``
@@ -65,6 +67,9 @@ from repro.serving import (AsyncServer, Query, RecommendationEngine,  # noqa: E4
 
 N_TX, N_ITEMS, MIN_SUPPORT = 100_000, 1_000, 0.01
 ROW_TILE = 512          # pinned kernel row tile in the kernels phase
+# row counts the kernels phase also counts at the cached config: a 32-tile
+# round's tile of 120,000 rows and a four-chip shard of 100,000
+OFF_LATTICE_ROWS = (3752, 25000)
 
 
 class SmokeFailure(RuntimeError):
@@ -179,6 +184,15 @@ def phase_kernels(T: np.ndarray, supports, rules, n_queries: int,
         check(np.array_equal(got, want),
               f"kernels: support_count {cfg} differs from the jnp ref")
         blocks[f"support_count/{cfg['variant']}"] = n_rows // ROW_TILE
+    # the checked-in cache's config, fitted to row counts no sweep measured
+    for rows in OFF_LATTICE_ROWS:
+        got = np.asarray(support_count(Tj[:rows], Cj, interpret=interpret))
+        check(np.array_equal(got, np.asarray(support_count_ref(Tj[:rows],
+                                                               Cj))),
+              f"kernels: support_count at {rows} rows differs from the "
+              f"jnp ref")
+        blocks[f"support_count/cache@{rows}"] = \
+            rows // LAST_DISPATCH["support_count"]["config"]["bn"]
     # Eclat: candidate pairs' tid-lists, several row and word blocks
     cols = pack_tid_columns(T)
     pairs = sorted(s for s in supports if len(s) == 2)

@@ -180,22 +180,24 @@ def default_cache(reload: bool = False) -> AutotuneCache:
     return _default
 
 
-def _resolve(kernel: str, shape: Tuple[int, ...],
-             tuning: Any) -> Tuple[Dict[str, Any], str]:
+def _resolve(kernel: str, shape: Tuple[int, ...], tuning: Any,
+             device: Optional[str] = None) -> Tuple[Dict[str, Any], str]:
     """(config, source) with source ``pinned`` | ``cache`` | ``roofline``."""
-    from repro.launch.tuning import default_config
+    from repro.launch.tuning import default_config, fit_config
     if isinstance(tuning, dict):
         return dict(tuning), "pinned"
     if tuning is not False:
         cache = tuning if isinstance(tuning, AutotuneCache) else default_cache()
-        entry = cache.lookup(kernel, shape)
-        if entry is not None:
-            return dict(entry["config"]), "cache"
+        entry = cache.lookup(kernel, shape, device)
+        cfg = None if entry is None else fit_config(kernel, shape,
+                                                    entry["config"])
+        if cfg is not None:
+            return cfg, "cache"
     return default_config(kernel, shape), "roofline"
 
 
-def resolve_config(kernel: str, shape: Tuple[int, ...],
-                   tuning: Any = None) -> Dict[str, Any]:
+def resolve_config(kernel: str, shape: Tuple[int, ...], tuning: Any = None,
+                   device: Optional[str] = None) -> Dict[str, Any]:
     """The config one kernel launch at ``shape`` runs with.
 
     ``tuning`` selects the source of the config:
@@ -205,10 +207,14 @@ def resolve_config(kernel: str, shape: Tuple[int, ...],
       * an :class:`AutotuneCache` — that cache (tuner round-trips, CI
         smoke sweeps writing to a scratch path).
 
-    Cache misses — including cold/corrupt caches and unknown device
-    kinds — fall back to :func:`repro.launch.tuning.default_config`.
+    A cached config is fitted to ``shape``
+    (:func:`repro.launch.tuning.fit_config`).  Cache misses — including
+    cold/corrupt caches and unknown device kinds — and cached configs that
+    do not fit ``shape`` fall back to
+    :func:`repro.launch.tuning.default_config`.  ``device`` is the device
+    kind the cache is keyed by (default: this process's).
     """
-    return _resolve(kernel, shape, tuning)[0]
+    return _resolve(kernel, shape, tuning, device)[0]
 
 
 # kernel -> what its ops wrapper last launched: the padded shape, the

@@ -31,7 +31,7 @@ from repro.kernels.rule_match.kernel import rule_scores_pallas
 from repro.kernels.rule_match.ref import rule_scores_ref
 from repro.kernels.support_count.fused import support_count_fused
 from repro.kernels.support_count.intersect import intersect_count_pallas
-from repro.kernels.support_count.kernel import support_count_pallas
+from repro.kernels.support_count.kernel import support_count_mxu
 from repro.kernels.support_count.ref import (intersect_count_ref,
                                              support_count_ref)
 from repro.launch.tuning import kernel_candidates, seed_order
@@ -82,9 +82,7 @@ def make_inputs(kernel: str, shape: Tuple[int, ...], seed: int = 0
     for r in range(m):
         A[r, rng.choice(i, size=1 + r % 4, replace=False)] = 1
     if kernel == "support_count":
-        sizes = A.astype(np.float32).sum(axis=1)[None, :]
-        return {"T": jnp.asarray(X), "C": jnp.asarray(A),
-                "sizes": jnp.asarray(sizes)}
+        return {"T": jnp.asarray(X), "C": jnp.asarray(A)}
     # rule_match: last eighth of the rows are index padding (sizes=-1)
     pad_from = m - max(m // 8, 1)
     sizes = A.astype(np.float32).sum(axis=1)
@@ -107,12 +105,12 @@ def run_config(kernel: str, config: Dict[str, Any],
                                       bm=cfg["bm"], bw=cfg["bw"],
                                       interpret=interpret)
     if kernel == "support_count":
-        T, C, sizes = inputs["T"], inputs["C"], inputs["sizes"]
+        T, C = inputs["T"], inputs["C"]
         if variant == "packed":
             return support_count_fused(T, C, bn=cfg["bn"], bm=cfg["bm"],
                                        interpret=interpret)
-        return support_count_pallas(T, C, sizes, bn=cfg["bn"], bm=cfg["bm"],
-                                    bi=cfg["bi"], interpret=interpret)
+        return support_count_mxu(T, C, bn=cfg["bn"], bm=cfg["bm"],
+                                 bi=cfg["bi"], interpret=interpret)
     Q, A = inputs["Q"], inputs["A"]
     sizes, conf = inputs["sizes"], inputs["conf"]
     if variant == "packed":
@@ -196,12 +194,19 @@ def standard_shapes(kernel: str, smoke: bool = False
     """The sweep lattice: one shape per bucket the planes actually hit
     (B6 tiles 64-1024 rows x 128-2048 candidates; B7 buckets 1-64
     queries x 128-512 index rows), nearest-bucket lookup covers the
-    rest.  ``smoke`` shrinks to one tiny shape for the CI sweep leg."""
+    rest.  ``smoke`` shrinks to one tiny shape for the CI sweep leg.
+
+    support_count also sweeps the tiles a 100,000 x 1,000 Quest corpus
+    (``bench/configs/quest-t10i4d100k.json``) puts through it: a 32-tile
+    round's row tile against the k=2 level (113,050 candidates) and
+    against a deep one, and ``chip_smoke.py``'s kernels phase.  Those
+    are sized for the chip; interpret mode would take hours there."""
     if kernel == "support_count":
         if smoke:
             return [(64, 128, 128)]
         return [(n, m, 128) for n in (64, 256, 1024)
-                for m in (128, 256, 512, 2048)]
+                for m in (128, 256, 512, 2048)] + [
+            (3128, 113152, 1024), (3128, 128, 1024), (99840, 384, 1024)]
     if kernel == "intersect_count":
         # Eclat rounds: candidate count varies widely, word axis is
         # W = ceil(n_tx/32) padded to 128 lanes (128 words ≈ 4k tx)

@@ -15,7 +15,9 @@ Tiling (HBM→VMEM):
   depends on the outer axis only, so it stays resident across every
   (N, I) step that revisits it — the one revisit pattern TPU Pallas
   supports (an output block is written back when its index changes and
-  never read again).
+  never read again).  With ``bi == I`` the item grid has one step, and
+  with ``bn == N`` too (a round's row tile) the T block index never
+  changes, so the tile is fetched once per launch.
 
 Block defaults (bn=512, bm=256, bi=512, int8 inputs):
   VMEM ≈ 2·512·512 (T) + 2·256·512 (C) + 3·512·256·4 (acc + temps)
@@ -82,3 +84,14 @@ def support_count_pallas(T: jnp.ndarray, C: jnp.ndarray, sizes: jnp.ndarray,
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(T, C, sizes.astype(jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnames=("bn", "bm", "bi", "interpret"))
+def support_count_mxu(T: jnp.ndarray, C: jnp.ndarray, *, bn: int = 512,
+                      bm: int = 256, bi: int = 512,
+                      interpret: bool = False) -> jnp.ndarray:
+    """0/1 int8 bitmaps in, counts out: derives |C_m| (fused into this jit,
+    one read of C) and runs the kernel.  Returns [1, M] int32."""
+    sizes = jnp.sum(C, axis=1, dtype=jnp.int32)[None, :]       # [1, M]
+    return support_count_pallas(T, C, sizes, bn=bn, bm=bm, bi=bi,
+                                interpret=interpret)

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from repro.kernels.autotune.cache import dispatch
 from repro.kernels.support_count.fused import support_count_fused
 from repro.kernels.support_count.intersect import intersect_count_pallas
-from repro.kernels.support_count.kernel import support_count_pallas
+from repro.kernels.support_count.kernel import support_count_mxu
 from repro.kernels.support_count.ref import intersect_count_ref, support_count_ref
 
 
@@ -67,10 +67,9 @@ def support_count(T: jnp.ndarray, C: jnp.ndarray, *,
     if cfg.get("variant", "mxu") == "packed":
         out = support_count_fused(T, C, bn=bn, bm=bm, interpret=interpret)
     else:
-        sizes = C.astype(jnp.int32).sum(axis=1)[None, :]        # [1, M]
         bi = _fit(cfg.get("bi", 512), I)
-        out = support_count_pallas(T, C, sizes, bn=bn, bm=bm, bi=bi,
-                                   interpret=interpret)
+        out = support_count_mxu(T, C, bn=bn, bm=bm, bi=bi,
+                                interpret=interpret)
     counts = out[0, :M0]
     # padded transaction rows are all-zero: they can only match |c|=0 sets,
     # which do not occur among real candidates (Apriori starts at k=1).

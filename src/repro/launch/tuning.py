@@ -24,7 +24,7 @@ erroring.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 from repro.configs.base import ModelConfig
 from repro.launch.roofline import HBM_BW, PEAK_FLOPS
@@ -167,6 +167,43 @@ def _fit_tile(want: int, dim: int, floor: int = 1) -> int:
     return max(t, 1)
 
 
+# support_count's block rules on a TPU: T [bn, bi], C [bm, bi] (packed:
+# [bn, W] and [W, bm]) and the [1, bm] sizes and counts.  A block's last
+# dim is a multiple of 128 lanes and the one before it of 8 sublanes,
+# unless the block spans the whole dim.  Key -> (shape axis, alignment).
+_SUPPORT_COUNT_TILES = {"bn": (0, 8), "bm": (1, 128), "bi": (2, 128)}
+
+
+def _fit_aligned(want: int, dim: int, align: int) -> int:
+    """The whole dim when it is no larger than ``want``, else the largest
+    multiple of ``align`` <= want that divides it (the ops wrappers pad
+    every dim to its alignment, so ``align`` itself always does)."""
+    if dim <= want:
+        return dim
+    t = max(align, want - want % align)
+    while dim % t:
+        t -= align
+    return t
+
+
+def fit_config(kernel: str, shape: Tuple[int, ...],
+               config: Dict[str, Any]) -> Optional[Dict[str, Any]]:
+    """A cached config fitted to the padded call ``shape`` it is used at:
+    the cache hands a bucket's (or the nearest bucket's) winner to every
+    shape near it, whose tiles need not divide this one's dims.  Each
+    support_count tile shrinks to the largest aligned divisor of its dim;
+    None when the fitted tiles overrun ``VMEM_BUDGET_BYTES``, so the caller
+    falls back to :func:`default_config`.  The other kernels' configs pass
+    as they are (their ops wrappers fit them)."""
+    cfg = dict(config)
+    if kernel != "support_count":
+        return cfg
+    for key, (axis, align) in _SUPPORT_COUNT_TILES.items():
+        if key in cfg:
+            cfg[key] = _fit_aligned(int(cfg[key]), shape[axis], align)
+    return cfg if vmem_bytes(kernel, shape, cfg) <= VMEM_BUDGET_BYTES else None
+
+
 def kernel_candidates(kernel: str, shape: Tuple[int, ...]
                       ) -> List[Dict[str, Any]]:
     """The swept config space for one kernel at one (padded) shape.
@@ -200,15 +237,24 @@ def kernel_candidates(kernel: str, shape: Tuple[int, ...]
             for ww in (512, 128, w):
                 add({"variant": "packed", "bm": _fit_tile(wm, m),
                      "bw": _fit_tile(ww, w)})
-    else:
+    elif kernel == "support_count":
+        # the item tile also spans the whole item axis (bi = I: one item
+        # step, and with bn = N the row tile is fetched once per launch)
         n, m, i = shape
-        a, b = ("bn", "bm") if kernel == "support_count" else ("bb", "br")
         for wn in (512, 256, n):
-            for wm in (256, 128, m):
-                add({"variant": "mxu", a: _fit_tile(wn, n),
-                     b: _fit_tile(wm, m), "bi": _fit_tile(512, i)})
-                add({"variant": "packed", a: _fit_tile(wn, n),
-                     b: _fit_tile(wm, m)})
+            for wm in (512, 256, 128, m):
+                tn, tm = _fit_tile(wn, n), _fit_tile(wm, m)
+                for ti in (_fit_tile(512, i), i):
+                    add({"variant": "mxu", "bn": tn, "bm": tm, "bi": ti})
+                add({"variant": "packed", "bn": tn, "bm": tm})
+    else:
+        b, r, i = shape
+        for wb in (512, 256, b):
+            for wr in (256, 128, r):
+                add({"variant": "mxu", "bb": _fit_tile(wb, b),
+                     "br": _fit_tile(wr, r), "bi": _fit_tile(512, i)})
+                add({"variant": "packed", "bb": _fit_tile(wb, b),
+                     "br": _fit_tile(wr, r)})
     return [c for c in cands
             if vmem_bytes(kernel, shape, c) <= VMEM_BUDGET_BYTES]
 

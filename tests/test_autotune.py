@@ -26,13 +26,16 @@ from repro.kernels.autotune.tuner import standard_shapes, tune, tune_into
 from repro.kernels.support_count.ops import support_count
 from repro.kernels.support_count.ref import support_count_ref
 from repro.launch.tuning import (TUNABLE_KERNELS, VMEM_BUDGET_BYTES,
-                                 default_config, kernel_candidates,
-                                 shape_flops_bytes, vmem_bytes)
+                                 default_config, fit_config,
+                                 kernel_candidates, shape_flops_bytes,
+                                 vmem_bytes)
 from repro.pipeline import MarketBasketPipeline, PipelineConfig
 from repro.runtime import (CostModelPolicy, MeasuredPhase, Runtime,
                            autotuned_costmodel)
 
 SC_SMOKE = (64, 128, 128)       # 2 candidates at this shape: one per variant
+V5E = "TPU_v5_lite"             # the chip the benchmark runs on, cache-keyed
+SC_K2 = (3128, 113152, 1024)    # its k=2 round's row tile (1% of T10I4D100K)
 
 
 # ---------------------------------------------------------------------------
@@ -87,6 +90,40 @@ def test_checked_in_cache_covers_both_kernels():
             assert "variant" in ent["config"]
 
 
+def test_checked_in_v5e_entries_are_measured_support_count_winners():
+    cache = default_cache(reload=True)
+    entries = {k: e for k, e in cache.entries.items()
+               if k.endswith(f"|{V5E}")}
+    assert entries
+    for key, ent in entries.items():
+        # serving's kernels keep their roofline dispatch on the chip
+        assert key.startswith("support_count|"), key
+        assert ent["source"] == "measured" and ent["cost_us"] > 0
+        winner = [s for s in ent["swept"] if s["config"] == ent["config"]]
+        assert winner and winner[0]["matched"], key
+        assert winner[0]["cost_us"] == min(
+            s["cost_us"] for s in ent["swept"] if s["matched"])
+    for kernel in ("rule_match", "intersect_count"):
+        assert not cache.entries_for(kernel, V5E)
+    # the tuner's own lattice plus the corpus tiles, one entry each
+    assert {tuple(e["shape"]) for e in entries.values()} \
+        == set(standard_shapes("support_count"))
+
+
+def test_k2_bucket_resolves_to_its_measured_winner():
+    cache = default_cache(reload=True)
+    key = AutotuneCache.key("support_count", SC_K2, V5E)
+    assert cache.lookup("support_count", SC_K2, V5E) is cache.entries[key]
+    # a nearby row tile shares the bucket; a cpu lookup never crosses over
+    assert cache.lookup("support_count", (3125, 113050, 1000), V5E) \
+        is cache.entries[key]
+    cpu = cache.lookup("support_count", SC_K2, "cpu")
+    assert cpu is not None and cpu in cache.entries_for("support_count", "cpu")
+    for ent in cache.entries_for("support_count", "cpu"):
+        assert cache.lookup("support_count", tuple(ent["shape"]), "cpu") \
+            is ent
+
+
 # ---------------------------------------------------------------------------
 # degradation: cold / corrupt caches fall back to roofline defaults
 # ---------------------------------------------------------------------------
@@ -138,6 +175,67 @@ def test_dispatch_records_config_source_and_interpret():
         "support_count", (32, 128, 128))
 
 
+# padded support_count shapes on the chip that no sweep measured, each
+# resolved through a nearby bucket's v5e winner: a 120,000-row corpus's
+# 32-tile k=2 round, a four-chip shard's 25,000 rows, and a smoke-sized
+# corpus against 512 candidates
+OFF_LATTICE = [(3752, 113152, 1024), (25000, 113152, 1024),
+               (99840, 512, 1024)]
+
+
+def _valid_tiles(shape, cfg):
+    """Every tile divides its dim and keeps the TPU block rules."""
+    n, m, i = shape
+    tiles = [(cfg["bn"], n, 8), (cfg["bm"], m, 128)]
+    if cfg["variant"] == "mxu":
+        tiles.append((cfg["bi"], i, 128))
+    return all(dim % t == 0 and (t == dim or t % align == 0)
+               for t, dim, align in tiles)
+
+
+@pytest.mark.parametrize("shape", OFF_LATTICE)
+def test_v5e_entries_fit_shapes_off_the_lattice(shape):
+    cfg = resolve_config("support_count", shape, device=V5E)
+    assert _valid_tiles(shape, cfg), cfg
+    assert vmem_bytes("support_count", shape, cfg) <= VMEM_BUDGET_BYTES
+
+
+@pytest.mark.parametrize("want, shape, got", [
+    # a row tile that does not divide N shrinks to an aligned divisor
+    ({"variant": "mxu", "bn": 3128, "bm": 512, "bi": 1024},
+     (3752, 113152, 1024), {"bn": 536, "bm": 512, "bi": 1024}),
+    ({"variant": "mxu", "bn": 3128, "bm": 256, "bi": 512},
+     (25000, 113152, 1024), {"bn": 1000, "bm": 256, "bi": 512}),
+    # a candidate tile of 384 lanes against 512 candidates
+    ({"variant": "mxu", "bn": 512, "bm": 384, "bi": 1024},
+     (99840, 512, 1024), {"bn": 512, "bm": 256, "bi": 1024}),
+    # tiles wider than a small shape span the whole dim
+    ({"variant": "packed", "bn": 3128, "bm": 512}, (40, 128, 256),
+     {"bn": 40, "bm": 128}),
+])
+def test_fit_config_shrinks_tiles_to_aligned_divisors(want, shape, got):
+    assert fit_config("support_count", shape, want) \
+        == {"variant": want["variant"], **got}
+    # a config that already fits its own shape is left as it is
+    assert fit_config("support_count", shape, {**want, **got}) \
+        == {**want, **got}
+
+
+def test_cached_config_over_the_vmem_budget_falls_back_to_default():
+    # whole-axis MXU tiles at 2,048 candidates x 2,048 items: ~21 MiB of
+    # double-buffered blocks alone
+    shape = (3128, 2048, 2048)
+    big = {"variant": "mxu", "bn": 3128, "bm": 2048, "bi": 2048}
+    assert fit_config("support_count", shape, big) is None
+    cache = AutotuneCache()
+    cache.put("support_count", shape, big, 1.0, device=V5E)
+    assert resolve_config("support_count", shape, cache, device=V5E) \
+        == default_config("support_count", shape)
+    # the other kernels' configs pass through (their wrappers fit them)
+    cfg = {"variant": "packed", "bb": 64, "br": 384}
+    assert fit_config("rule_match", (512, 896, 1024), cfg) == cfg
+
+
 @pytest.mark.parametrize("kernel,shape", [
     ("support_count", (3128, 2176, 1024)),   # one 100k-tx row tile, k=2
     ("rule_match", (512, 1920, 1024)),
@@ -153,6 +251,10 @@ def test_candidates_fit_the_vmem_budget(kernel, shape):
         whole = {"variant": "mxu", "bn": 3128, "bm": 2176, "bi": 512}
         assert vmem_bytes(kernel, shape, whole) > VMEM_BUDGET_BYTES
         assert whole not in cands
+        # a row tile with the whole item axis (bi = I) is swept, but not
+        # with every candidate at once
+        assert {"variant": "mxu", "bn": 3128, "bm": 128, "bi": 1024} in cands
+        assert {**whole, "bi": 1024} not in cands
 
 
 def test_autotuned_costmodel_degrades_to_roofline():

@@ -174,6 +174,24 @@ def test_support_count_empty_candidates():
     assert out.shape == (0,) and out.dtype == np.int32
 
 
+@pytest.mark.parametrize("n", [40, 1064])
+def test_resident_mxu_tile_exact(n):
+    """bn == N and bi == I: the MXU kernel's one-step item grid with the
+    whole transaction tile resident for the launch, at N a multiple of 8
+    but not of 32 (the int8 sublane tile)."""
+    rng = np.random.default_rng(n)
+    T = (rng.random((n, 256)) < 0.3).astype(np.uint8)
+    C = np.zeros((200, 256), np.uint8)
+    for r in range(200):
+        C[r, rng.choice(256, size=1 + r % 4, replace=False)] = 1
+    got = np.asarray(support_count(
+        jnp.asarray(T), jnp.asarray(C),
+        tuning={"variant": "mxu", "bn": n, "bm": 128, "bi": 256}))
+    np.testing.assert_array_equal(
+        got, np.asarray(support_count_ref(jnp.asarray(T), jnp.asarray(C))))
+    np.testing.assert_array_equal(got, np_support_count(T, C))
+
+
 def test_rule_topk_empty_rules():
     Q = (np.random.default_rng(1).random((4, 32)) < 0.4).astype(np.uint8)
     empty = np.zeros((0, 32), np.uint8)

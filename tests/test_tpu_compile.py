@@ -4,9 +4,12 @@ The TPU compiler is installed with JAX and compiles for a chip that is
 described, not attached, so these tests catch what interpret mode cannot:
 Mosaic lowering errors (e.g. float accumulation of int8 operands, unsigned
 reductions) and kernels that overrun scoped VMEM.  Shapes are those of
-``chip_smoke.py`` at 100,000 transactions x 1,000 items; configs are the
-ones the ops wrappers resolve on a TPU (a cache miss, so the roofline
-default) or the ones the smoke pins.  Nothing runs and nothing is timed.
+``chip_smoke.py`` and the benchmark's corpus at 100,000 transactions x
+1,000 items, and three support_count shapes beside them; configs are the ones the ops wrappers resolve on a TPU (the
+checked-in cache's measured v5e entries for support_count, fitted to the
+shape, a cache miss and so the roofline default for the other kernels) or
+the ones the smoke pins.
+Nothing runs and nothing is timed.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and every test worker imports
@@ -20,10 +23,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.autotune.cache import default_cache
+from repro.kernels.autotune.cache import default_cache, resolve_config
 from repro.kernels.rule_match.ops import rule_topk
 from repro.kernels.support_count.ops import intersect_count, support_count
-from repro.launch.tuning import VMEM_BUDGET_BYTES, default_config, vmem_bytes
+from repro.launch.tuning import VMEM_BUDGET_BYTES, vmem_bytes
 
 V5E_KIND = "TPU_v5_lite"        # jax device_kind "TPU v5 lite", cache-keyed
 
@@ -33,6 +36,14 @@ CASES = [
     # Apriori k=2 round: one of 32 row tiles (3,125 rows -> 3,128) x the
     # 2,145 candidates bucketed to 2,176
     ("support_count", (3128, 2176, 1024), None),
+    # the benchmark's k=2 round at 1% support: 113,050 candidates -> 113,152
+    ("support_count", (3128, 113152, 1024), None),
+    # shapes no sweep measured, resolved through a nearby bucket's entry
+    # fitted to them: a 120,000-row corpus's 32-tile k=2 round, a four-chip
+    # shard's 25,000 rows, and the smoke's rows against 512 candidates
+    ("support_count", (3752, 113152, 1024), None),
+    ("support_count", (25000, 113152, 1024), None),
+    ("support_count", (99840, 512, 1024), None),
     # the smoke's kernels phase: 99,840 rows, 195 row blocks of 512
     ("support_count", (99840, 384, 1024),
      {"variant": "mxu", "bn": 512, "bm": 128, "bi": 512}),
@@ -68,10 +79,16 @@ def one_chip(topo):
 def _config(kernel, shape, pinned):
     if pinned is not None:
         return pinned
-    # what resolve_config does on the chip: the checked-in cache has no
-    # entry for this device kind, so the roofline default applies
-    assert default_cache().lookup(kernel, shape, device=V5E_KIND) is None
-    return default_config(kernel, shape)
+    # what resolve_config does on the chip: support_count resolves through
+    # the checked-in cache's measured v5e entries (nearest bucket, fitted
+    # to the shape); the other kernels have none, so the roofline default
+    # applies
+    entry = default_cache().lookup(kernel, shape, device=V5E_KIND)
+    if kernel == "support_count":
+        assert entry is not None and entry["source"] == "measured"
+    else:
+        assert entry is None
+    return resolve_config(kernel, shape, device=V5E_KIND)
 
 
 def _lowered(kernel, shape, cfg, one_chip):
@@ -108,6 +125,9 @@ def test_kernel_compiles_for_v5e(kernel, shape, pinned, one_chip):
 NAMED = [
     ("support_count", (99840, 384, 1024),
      {"variant": "packed", "bn": 512, "bm": 128}, "support_count_fused_pallas"),
+    ("support_count", (3128, 128, 1024),
+     {"variant": "mxu", "bn": 3128, "bm": 128, "bi": 1024},
+     "support_count_pallas"),
     ("rule_match", (512, 896, 1024),
      {"variant": "packed", "bb": 64, "br": 128}, "rule_scores_fused_pallas"),
 ]
