@@ -256,7 +256,9 @@ def phase_serve(rules, n_items: int, n_queries: int,
     return {"phase": "serve", "backend": engine.backend,
             "dispatch": dispatched("rule_match"), "wall_s": wall,
             "queries": len(handles), "answered": answered,
-            "index_rows": index.n_rows, "steps": report.n_steps}
+            "index_rules": index.n_rules, "index_rows": index.n_rows,
+            "index_rows_padded": index.n_rows_padded,
+            "steps": report.n_steps}
 
 
 def phase_sharded(T: np.ndarray, min_support: float, n_devices: int,

@@ -200,7 +200,13 @@ def standard_shapes(kernel: str, smoke: bool = False
     (``bench/configs/quest-t10i4d100k.json``) puts through it: a 32-tile
     round's row tile against the k=2 level (113,050 candidates) and
     against a deep one, and ``chip_smoke.py``'s kernels phase.  Those
-    are sized for the chip; interpret mode would take hours there."""
+    are sized for the chip; interpret mode would take hours there.
+
+    rule_match also sweeps the shapes the benchmark's serving cells
+    launch over 1,000 items (1,024 lanes) at the 8- and 64-basket
+    buckets: the 0.5%-support index (6,186 rows, 6,272 padded) and the
+    1% one (32 rows, 128 padded), so each resolves a measured entry of
+    its own."""
     if kernel == "support_count":
         if smoke:
             return [(64, 128, 128)]
@@ -215,7 +221,8 @@ def standard_shapes(kernel: str, smoke: bool = False
         return [(m, w) for m in (128, 512, 2048) for w in (128, 256)]
     if smoke:
         return [(8, 128, 128)]
-    return [(b, r, 128) for b in (8, 64) for r in (128, 512)]
+    return [(b, r, 128) for b in (8, 64) for r in (128, 512)] + [
+        (b, r, 1024) for r in (6272, 128) for b in (8, 64)]
 
 
 def tune_into(cache: AutotuneCache, kernel: str,

@@ -37,14 +37,6 @@ def _pad_axis_to(x: jnp.ndarray, axis: int, target: int) -> jnp.ndarray:
     return jnp.pad(x, widths)
 
 
-def _fit(want: int, dim: int) -> int:
-    """Shrink a cached/heuristic tile until it divides the padded dim."""
-    t = max(1, min(int(want), dim))
-    while dim % t:
-        t //= 2
-    return max(t, 1)
-
-
 @functools.partial(jax.jit,
                    static_argnames=("k", "backend", "variant", "interpret",
                                     "bb", "br", "bi"))
@@ -80,7 +72,9 @@ def rule_topk(Q: jnp.ndarray, A: jnp.ndarray, sizes: jnp.ndarray,
 
     ``tuning``: ``None`` = the checked-in autotune cache; ``False`` =
     roofline-seeded default config; a config ``dict`` or an
-    ``AutotuneCache`` pins the choice.
+    ``AutotuneCache`` pins the choice.  A pinned dict runs as it is (each
+    tile no larger than its padded dim must divide it); a cached config is
+    fitted to the padded shape first.
     """
     if backend is None:
         backend = "pallas" if jax.default_backend() == "tpu" else "ref"
@@ -106,14 +100,12 @@ def rule_topk(Q: jnp.ndarray, A: jnp.ndarray, sizes: jnp.ndarray,
     cons = jnp.pad(jnp.asarray(cons, jnp.int32), (0, pad_r),
                    constant_values=Ip)
     B, _ = Q.shape
+    # a cached config arrives fitted to (B, Rp, Ip) (launch/tuning.fit_config)
     cfg, interpret = dispatch("rule_match", (B, Rp, Ip), tuning, interpret)
-    bb = _fit(cfg.get("bb", 256), B)
-    br = _fit(cfg.get("br", 256), Rp)
-    bi = _fit(cfg.get("bi", 512), Ip)
     items, scores = _rule_topk(Q, A, sizes, conf, cons, n_items, k=k,
-                               backend=backend,
-                               variant=cfg.get("variant", "mxu"),
-                               interpret=interpret, bb=bb, br=br, bi=bi)
+                               backend=backend, variant=cfg["variant"],
+                               interpret=interpret, bb=cfg["bb"],
+                               br=cfg["br"], bi=cfg.get("bi"))
     return items[:B0], scores[:B0]
 
 

@@ -133,7 +133,7 @@ def recommend(n_tx: int = 8192, n_items: int = 128,
 
     # 2. compile the rule index (optionally persist it)
     index = RuleIndex.build(result.rules, n_items)
-    print(f"[recommend] index: {index.n_rows} rows "
+    print(f"[recommend] index: {index.n_rules} rules in {index.n_rows} rows "
           f"({index.n_rows_padded}x{index.n_items_padded} padded, "
           f"{index.nbytes / 1024:.0f} KiB)")
     if index_dir:

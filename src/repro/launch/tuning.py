@@ -172,6 +172,11 @@ def _fit_tile(want: int, dim: int, floor: int = 1) -> int:
 # dim is a multiple of 128 lanes and the one before it of 8 sublanes,
 # unless the block spans the whole dim.  Key -> (shape axis, alignment).
 _SUPPORT_COUNT_TILES = {"bn": (0, 8), "bm": (1, 128), "bi": (2, 128)}
+# rule_match's: Q [bb, bi], A [br, bi] (packed: [bb, W] and [W, br]), the
+# [1, br] sizes and confidences and the [bb, br] scores.
+_RULE_MATCH_TILES = {"bb": (0, 8), "br": (1, 128), "bi": (2, 128)}
+_TILES = {"support_count": _SUPPORT_COUNT_TILES,
+          "rule_match": _RULE_MATCH_TILES}
 
 
 def _fit_aligned(want: int, dim: int, align: int) -> int:
@@ -191,14 +196,16 @@ def fit_config(kernel: str, shape: Tuple[int, ...],
     """A cached config fitted to the padded call ``shape`` it is used at:
     the cache hands a bucket's (or the nearest bucket's) winner to every
     shape near it, whose tiles need not divide this one's dims.  Each
-    support_count tile shrinks to the largest aligned divisor of its dim;
-    None when the fitted tiles overrun ``VMEM_BUDGET_BYTES``, so the caller
-    falls back to :func:`default_config`.  The other kernels' configs pass
-    as they are (their ops wrappers fit them)."""
+    support_count and rule_match tile shrinks to the largest aligned
+    divisor of its dim; None when the fitted tiles overrun
+    ``VMEM_BUDGET_BYTES``, so the caller falls back to
+    :func:`default_config`.  intersect_count configs pass as they are (its
+    ops wrapper fits them)."""
     cfg = dict(config)
-    if kernel != "support_count":
+    tiles = _TILES.get(kernel)
+    if tiles is None:
         return cfg
-    for key, (axis, align) in _SUPPORT_COUNT_TILES.items():
+    for key, (axis, align) in tiles.items():
         if key in cfg:
             cfg[key] = _fit_aligned(int(cfg[key]), shape[axis], align)
     return cfg if vmem_bytes(kernel, shape, cfg) <= VMEM_BUDGET_BYTES else None

@@ -32,6 +32,7 @@ from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import store as ckpt_store
 from repro.core.rules import Rule
@@ -71,7 +72,14 @@ class RuleIndex:
     @classmethod
     def build(cls, rules: Sequence[Rule], n_items: int, *,
               r_bucket: int = 128, version: int = 0) -> "RuleIndex":
-        """Deterministic lowering (stable total order; see module docstring)."""
+        """Deterministic lowering (stable total order; see module
+        docstring), inside the profiler span ``serve-index-build``."""
+        with TraceAnnotation("serve-index-build"):
+            return cls._lower(rules, n_items, r_bucket, version)
+
+    @classmethod
+    def _lower(cls, rules: Sequence[Rule], n_items: int, r_bucket: int,
+               version: int) -> "RuleIndex":
         if n_items <= 0:
             raise ValueError(f"n_items must be positive, got {n_items}")
         if r_bucket <= 0 or r_bucket % 128:

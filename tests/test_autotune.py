@@ -96,18 +96,18 @@ def test_checked_in_v5e_entries_are_measured_support_count_winners():
                if k.endswith(f"|{V5E}")}
     assert entries
     for key, ent in entries.items():
-        # serving's kernels keep their roofline dispatch on the chip
-        assert key.startswith("support_count|"), key
+        # Eclat's intersect_count keeps its roofline dispatch on the chip
+        assert key.startswith(("support_count|", "rule_match|")), key
         assert ent["source"] == "measured" and ent["cost_us"] > 0
         winner = [s for s in ent["swept"] if s["config"] == ent["config"]]
         assert winner and winner[0]["matched"], key
         assert winner[0]["cost_us"] == min(
             s["cost_us"] for s in ent["swept"] if s["matched"])
-    for kernel in ("rule_match", "intersect_count"):
-        assert not cache.entries_for(kernel, V5E)
-    # the tuner's own lattice plus the corpus tiles, one entry each
-    assert {tuple(e["shape"]) for e in entries.values()} \
-        == set(standard_shapes("support_count"))
+    assert not cache.entries_for("intersect_count", V5E)
+    # the tuner's own lattice plus the benchmark's shapes, one entry each
+    for kernel in ("support_count", "rule_match"):
+        assert {tuple(e["shape"]) for e in cache.entries_for(kernel, V5E)} \
+            == set(standard_shapes(kernel))
 
 
 def test_k2_bucket_resolves_to_its_measured_winner():
@@ -231,9 +231,100 @@ def test_cached_config_over_the_vmem_budget_falls_back_to_default():
     cache.put("support_count", shape, big, 1.0, device=V5E)
     assert resolve_config("support_count", shape, cache, device=V5E) \
         == default_config("support_count", shape)
-    # the other kernels' configs pass through (their wrappers fit them)
-    cfg = {"variant": "packed", "bb": 64, "br": 384}
-    assert fit_config("rule_match", (512, 896, 1024), cfg) == cfg
+    # intersect_count's configs pass through (its wrapper fits them)
+    cfg = {"variant": "packed", "bm": 512, "bw": 384}
+    assert fit_config("intersect_count", (128, 3200), cfg) == cfg
+
+
+# rule_match at the serving buckets (8 and 64 baskets, 1,024 lanes) over
+# the 0.5%-support index (6,272 padded rows), the same index after a rule
+# refresh (6,400) and the 1% index (128)
+RULE_SHAPES = [(b, r, 1024) for b in (8, 64) for r in (6272, 6400, 128)]
+
+
+def _valid_rule_tiles(shape, cfg):
+    """Every rule_match tile divides its dim and keeps the block rules:
+    the basket tile spans B or is a multiple of 8 sublanes, the row and
+    item tiles span their dim or are multiples of 128 lanes."""
+    b, r, i = shape
+    tiles = [(cfg["bb"], b, 8), (cfg["br"], r, 128)]
+    if cfg["variant"] == "mxu":
+        tiles.append((cfg["bi"], i, 128))
+    return all(dim % t == 0 and (t == dim or t % align == 0)
+               for t, dim, align in tiles)
+
+
+@pytest.mark.parametrize("shape", RULE_SHAPES)
+@pytest.mark.parametrize("want", [
+    {"variant": "mxu", "bb": 8, "br": 6272, "bi": 512},
+    {"variant": "mxu", "bb": 64, "br": 640, "bi": 1024},
+    {"variant": "packed", "bb": 64, "br": 6272},
+    {"variant": "packed", "bb": 24, "br": 384},
+])
+def test_fit_config_fits_rule_match_tiles_to_aligned_divisors(shape, want):
+    got = fit_config("rule_match", shape, want)
+    assert got is not None and _valid_rule_tiles(shape, got), got
+    assert got["variant"] == want["variant"] and got.keys() == want.keys()
+    for key, axis in (("bb", 0), ("br", 1), ("bi", 2)):
+        if key in want:        # a tile never grows, and only to fit
+            assert got[key] <= max(want[key], 1)
+            assert got[key] == want[key] or shape[axis] % want[key] \
+                or want[key] > shape[axis]
+    assert vmem_bytes("rule_match", shape, got) <= VMEM_BUDGET_BYTES
+
+
+@pytest.mark.parametrize("want, shape, got", [
+    # the whole 6,272-row index tile after a refresh to 6,400 rows
+    ({"variant": "mxu", "bb": 64, "br": 6272, "bi": 1024},
+     (64, 6400, 1024), {"bb": 64, "br": 3200, "bi": 1024}),
+    ({"variant": "packed", "bb": 8, "br": 6272}, (8, 6400, 1024),
+     {"bb": 8, "br": 3200}),
+    # a 640-row tile against 6,272 rows (49 x 128): the largest divisor
+    ({"variant": "mxu", "bb": 64, "br": 640, "bi": 512},
+     (64, 6272, 1024), {"bb": 64, "br": 128, "bi": 512}),
+    # tiles wider than the 1% index and the 8-basket bucket span them
+    ({"variant": "mxu", "bb": 64, "br": 6272, "bi": 1024},
+     (8, 128, 1024), {"bb": 8, "br": 128, "bi": 1024}),
+    # a basket tile of 24 against the 64-basket bucket
+    ({"variant": "packed", "bb": 24, "br": 128}, (64, 128, 1024),
+     {"bb": 16, "br": 128}),
+])
+def test_fit_config_rule_match_cases(want, shape, got):
+    assert fit_config("rule_match", shape, want) \
+        == {"variant": want["variant"], **got}
+    assert fit_config("rule_match", shape, {**want, **got}) \
+        == {**want, **got}
+
+
+def test_cached_rule_tile_refits_after_a_rule_refresh():
+    # a v5e entry measured at the 0.5% index's 6,272 rows, looked up at
+    # 6,400 rows: the row tile is fitted to a multiple of 128, never 1
+    cache = AutotuneCache()
+    cache.put("rule_match", (64, 6272, 1024),
+              {"variant": "packed", "bb": 64, "br": 6272}, 1.0, device=V5E)
+    for rows in (6400, 6528, 6272 + 128 * 3):
+        shape = (64, rows, 1024)
+        cfg = resolve_config("rule_match", shape, cache, device=V5E)
+        assert cfg["br"] % 128 == 0 and rows % cfg["br"] == 0, cfg
+        assert _valid_rule_tiles(shape, cfg)
+
+
+def test_rule_match_fit_over_the_vmem_budget_falls_back_to_default():
+    # a whole 2,048-basket x 8,192-row score tile alone is 64 MiB
+    shape = (2048, 8192, 1024)
+    big = {"variant": "mxu", "bb": 2048, "br": 8192, "bi": 1024}
+    assert fit_config("rule_match", shape, big) is None
+    cache = AutotuneCache()
+    cache.put("rule_match", shape, big, 1.0, device=V5E)
+    assert resolve_config("rule_match", shape, cache, device=V5E) \
+        == default_config("rule_match", shape)
+
+
+def test_rule_match_ops_have_one_fitting_path():
+    # the ops wrapper runs the dispatched config as it is: the one fit is
+    # launch/tuning.fit_config
+    import repro.kernels.rule_match.ops as rule_ops
+    assert not hasattr(rule_ops, "_fit")
 
 
 @pytest.mark.parametrize("kernel,shape", [

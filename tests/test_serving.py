@@ -7,8 +7,10 @@ import pytest
 import jax.numpy as jnp
 
 from repro.core.itemsets import apriori
-from repro.core.rules import generate_rules
+from repro.core.rules import Rule, generate_rules
 from repro.data.baskets import BasketConfig, generate_baskets
+from repro.kernels.autotune.cache import (LAST_DISPATCH, AutotuneCache,
+                                          device_kind)
 from repro.kernels.rule_match.ops import rule_topk
 from repro.kernels.rule_match.ref import recommend_ref
 from repro.pipeline import MarketBasketPipeline, PipelineConfig
@@ -63,6 +65,88 @@ def test_rule_topk_pallas_matches_ref_oracle(B, I, R, k):
         jnp.asarray(np.pad(cons, (0, Rp - R), constant_values=Ip)), I, k)
     np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i)[:B])
     np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s)[:B])
+
+
+@pytest.fixture(scope="module")
+def rich_index():
+    """A seeded rule-rich index: 1,100 distinct (antecedent, item) rows
+    over 256 items, padded to 1,152 rows (9 x 128, not a power of two),
+    and 64 baskets dense enough in the antecedents' items that most of
+    them match a rule."""
+    rng = np.random.default_rng(16)
+    n_items, rules, seen = 256, [], set()
+    while len(rules) < 1100:
+        ante = tuple(sorted(int(i) for i in rng.choice(
+            48, size=int(rng.integers(1, 4)), replace=False)))
+        item = int(rng.integers(0, n_items))
+        if item in ante or (ante, item) in seen:
+            continue
+        seen.add((ante, item))
+        rules.append(Rule(antecedent=ante, consequent=(item,),
+                          support=float(rng.random() * 0.05),
+                          confidence=float(0.6 + 0.4 * rng.random()),
+                          lift=float(1.0 + rng.random())))
+    index = RuleIndex.build(rules, n_items)
+    assert (index.n_rows, index.n_rows_padded) == (1100, 1152)
+    baskets = np.zeros((64, n_items), np.uint8)
+    baskets[:, :48] = rng.random((64, 48)) < 0.15
+    baskets[:, 48:] = rng.random((64, n_items - 48)) < 0.03
+    return rules, index, baskets
+
+
+@pytest.mark.parametrize("cached_br", [384, 640, 6272])
+@pytest.mark.parametrize("variant", ["mxu", "packed"])
+def test_rule_topk_fitted_dispatch_matches_oracles(rich_index, variant,
+                                                   cached_br):
+    """rule_topk through the fitted dispatch (a cached config whose row
+    tile is not a power of two, fitted to the 1,152-row index: 384 stays,
+    640 -> 384, 6,272 -> the whole 1,152) equals the brute-force oracle
+    and the jnp reference exactly."""
+    rules, index, baskets = rich_index
+    cache = AutotuneCache()
+    cfg = {"variant": variant, "bb": 64, "br": cached_br}
+    if variant == "mxu":
+        cfg["bi"] = 256
+    cache.put("rule_match", (64, 1152, 256), cfg, 1.0, device=device_kind())
+    args = [jnp.asarray(x) for x in (baskets, index.ante, index.sizes,
+                                     index.conf, index.cons)]
+    got_i, got_s = rule_topk(*args, k=5, n_items=256, backend="pallas",
+                             interpret=True, tuning=cache)
+    rec = LAST_DISPATCH["rule_match"]
+    assert rec["source"] == "cache" and rec["shape"] == (64, 1152, 256)
+    assert rec["config"]["br"] == (1152 if cached_br > 1152 else 384)
+    want_i, want_s = recommend_ref(*args, 256, 5)
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+    np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
+    answered = 0
+    for row, items, scores in zip(baskets, np.asarray(got_i),
+                                  np.asarray(got_s)):
+        got = [(int(i), float(sc)) for i, sc in zip(items, scores)
+               if sc > 0.0]
+        assert got == recommend_bruteforce(rules, np.nonzero(row)[0], 5)
+        answered += bool(got)
+    assert answered > len(baskets) // 2       # mostly non-empty answers
+
+
+def test_index_build_runs_in_its_span(monkeypatch, mined):
+    import repro.serving.index as index_mod
+    names = []
+
+    class Span:
+        def __init__(self, name):
+            names.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(index_mod, "TraceAnnotation", Span)
+    T, res = mined
+    index = RuleIndex.build(res.rules, T.shape[1])
+    assert names == ["serve-index-build"]
+    assert index.n_rules == len(res.rules) and index.n_rows > 0
 
 
 def test_rule_topk_padded_rows_never_match():

@@ -5,10 +5,12 @@ described, not attached, so these tests catch what interpret mode cannot:
 Mosaic lowering errors (e.g. float accumulation of int8 operands, unsigned
 reductions) and kernels that overrun scoped VMEM.  Shapes are those of
 ``chip_smoke.py`` and the benchmark's corpus at 100,000 transactions x
-1,000 items, and three support_count shapes beside them; configs are the ones the ops wrappers resolve on a TPU (the
-checked-in cache's measured v5e entries for support_count, fitted to the
-shape, a cache miss and so the roofline default for the other kernels) or
-the ones the smoke pins.
+1,000 items, three support_count shapes beside them, and the rule_match
+launches of the benchmark's rule-rich serving cell; configs are the ones
+the ops wrappers resolve on a TPU (the checked-in cache's measured v5e
+entries for support_count and rule_match, fitted to the shape, a cache
+miss and so the roofline default for intersect_count) or the ones the
+smoke pins.
 Nothing runs and nothing is timed.
 
 The topology is described inside a module-scoped fixture, never at import:
@@ -57,6 +59,10 @@ CASES = [
      {"variant": "mxu", "bb": 64, "br": 128, "bi": 512}),
     ("rule_match", (512, 896, 1024),
      {"variant": "packed", "bb": 64, "br": 128}),
+    # the rule-rich serving cell: the 64- and 8-basket buckets against the
+    # 0.5%-support index (6,186 rows -> 6,272)
+    ("rule_match", (64, 6272, 1024), None),
+    ("rule_match", (8, 6272, 1024), None),
 ]
 
 
@@ -79,15 +85,15 @@ def one_chip(topo):
 def _config(kernel, shape, pinned):
     if pinned is not None:
         return pinned
-    # what resolve_config does on the chip: support_count resolves through
-    # the checked-in cache's measured v5e entries (nearest bucket, fitted
-    # to the shape); the other kernels have none, so the roofline default
-    # applies
+    # what resolve_config does on the chip: support_count and rule_match
+    # resolve through the checked-in cache's measured v5e entries (nearest
+    # bucket, fitted to the shape); intersect_count has none, so the
+    # roofline default applies
     entry = default_cache().lookup(kernel, shape, device=V5E_KIND)
-    if kernel == "support_count":
-        assert entry is not None and entry["source"] == "measured"
-    else:
+    if kernel == "intersect_count":
         assert entry is None
+    else:
+        assert entry is not None and entry["source"] == "measured"
     return resolve_config(kernel, shape, device=V5E_KIND)
 
 
@@ -121,7 +127,8 @@ def test_kernel_compiles_for_v5e(kernel, shape, pinned, one_chip):
 
 
 # the benchmark's readers and its breakdown key on these device-op names
-# (`support_count_roofline` sums the ops named `support_count*`)
+# (`support_count_roofline` sums the ops named `support_count*`,
+# `rule_match_roofline` those named `rule_scores*`)
 NAMED = [
     ("support_count", (99840, 384, 1024),
      {"variant": "packed", "bn": 512, "bm": 128}, "support_count_fused_pallas"),
@@ -130,6 +137,8 @@ NAMED = [
      "support_count_pallas"),
     ("rule_match", (512, 896, 1024),
      {"variant": "packed", "bb": 64, "br": 128}, "rule_scores_fused_pallas"),
+    ("rule_match", (512, 896, 1024),
+     {"variant": "mxu", "bb": 64, "br": 128, "bi": 512}, "rule_scores_pallas"),
 ]
 
 
