@@ -120,7 +120,11 @@ def pack_transactions(transactions: Sequence[Sequence[int]],
     return T
 
 
-def pad_items(T: np.ndarray, multiple: int = 128) -> np.ndarray:
+# the kernels' lane width: the item axis is padded to a multiple of it
+ITEM_LANES = 128
+
+
+def pad_items(T: np.ndarray, multiple: int = ITEM_LANES) -> np.ndarray:
     """Pad the item axis to a lane-aligned multiple (kernel requirement)."""
     n_tx, n_items = T.shape
     pad = (-n_items) % multiple

@@ -43,6 +43,7 @@ from repro.core.itemsets import (AprioriResult, generate_candidates,
                                  itemsets_to_bitmap)
 from repro.core.power import PowerModel
 from repro.core.scheduler import MBScheduler, TaskSpec
+from repro.data.baskets import pad_items
 from repro.data.sharding import plan_shard_rows
 from repro.data.sparse import SparseSlab, density_stats
 from repro.distributed.fault import FaultPlan
@@ -459,6 +460,7 @@ class ShardedMiner:
         mark = rt.ledger.mark()
 
         T, n_items_raw, n_tx_raw = ingest_baskets(baskets)
+        T = pad_items(T)
         n_tx, n_items = T.shape                    # lane-padded (internal)
         min_sup = cfg.abs_support(n_tx_raw)
         n = self.profile.n
@@ -590,8 +592,8 @@ class ShardedMiner:
         Tw = np.ascontiguousarray(cols.T)
         # the smoke path re-counts every round against the dense oracle;
         # only then is the dense bitmap ever materialized on this plane
-        T_dense = (ingest_baskets(baskets)[0] if self.verify_rounds
-                   else None)
+        T_dense = (pad_items(ingest_baskets(baskets)[0])
+                   if self.verify_rounds else None)
 
         alive = np.ones(n, dtype=bool)
         plan = plan_shards(self.profile, Tw.shape[0], row_block=1,

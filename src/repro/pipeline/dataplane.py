@@ -20,13 +20,15 @@ true candidate count rather than trusting zeros.
 """
 from __future__ import annotations
 
-from typing import List, Optional
+import functools
+from typing import List, Optional, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
+from repro.data.baskets import ITEM_LANES
 from repro.kernels.support_count.ops import support_count as _pallas_count
 from repro.kernels.support_count.ref import support_count_ref as _ref_count
 from repro.runtime.transfers import METER, TransferMeter
@@ -52,6 +54,17 @@ def pad_candidates(C: np.ndarray, m_bucket: int) -> np.ndarray:
     return np.pad(C, ((0, pad), (0, 0)))
 
 
+def tile_geometry(n_tx: int, n_tiles: int,
+                  row_multiple: int = 8) -> Tuple[int, int]:
+    """``(tile count, rows per tile)`` of the uniform row tiling: the count
+    clamped to ``[1, n_tx]``, the rows ``ceil(n_tx / count)`` rounded up to
+    the kernel's sublane multiple."""
+    n_tiles = max(1, min(n_tiles, n_tx))
+    rows = -(-n_tx // n_tiles)                    # ceil
+    rows += (-rows) % row_multiple                # kernel sublane alignment
+    return n_tiles, rows
+
+
 def uniform_tiles(T: np.ndarray, n_tiles: int,
                   row_multiple: int = 8) -> List[np.ndarray]:
     """Split T into n_tiles row tiles of identical shape (zero-row padded).
@@ -61,12 +74,28 @@ def uniform_tiles(T: np.ndarray, n_tiles: int,
     empty itemset, which Apriori never emits (k >= 1).
     """
     n_tx = T.shape[0]
-    n_tiles = max(1, min(n_tiles, n_tx))
-    rows = -(-n_tx // n_tiles)                    # ceil
-    rows += (-rows) % row_multiple                # kernel sublane alignment
+    n_tiles, rows = tile_geometry(n_tx, n_tiles, row_multiple)
     padded = np.pad(T, ((0, rows * n_tiles - n_tx), (0, 0)))
     return [np.ascontiguousarray(padded[i * rows:(i + 1) * rows])
             for i in range(n_tiles)]
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "n_tiles"))
+def device_tiles(flat: jax.Array, shape: Tuple[int, int],
+                 n_tiles: int) -> Tuple[jax.Array, ...]:
+    """``uniform_tiles(pad_items(T), n_tiles)``, built on the device from
+    the raw bitmap ``T`` of ``shape``, uploaded as its flat bytes: one
+    program reshapes it, zero-pads the item axis to the lane multiple and
+    the rows to the tiling, then cuts the tiles.  The host uploads the
+    bitmap once and copies it never; a 1-D upload also spares the host
+    the 2-D array's relayout to the device's tiled layout, which 1,000
+    unaligned lanes need (TPU v5e: 0.018 s to a ready tile set, against
+    0.034-0.039 s for the 2-D upload)."""
+    n_tx, n_items = shape
+    n_tiles, rows = tile_geometry(n_tx, n_tiles)
+    padded = jnp.pad(flat.reshape(shape), ((0, rows * n_tiles - n_tx),
+                                           (0, (-n_items) % ITEM_LANES)))
+    return tuple(padded[i * rows:(i + 1) * rows] for i in range(n_tiles))
 
 
 class DataPlane:

@@ -40,9 +40,9 @@ from repro.core.mapreduce import FailureEvent, MapReduceJob, SimulatedCluster
 from repro.core.power import PowerModel
 from repro.core.scheduler import MBScheduler, TaskSpec
 from repro.core.rules import Rule, generate_rules
-from repro.data.baskets import pack_transactions, pad_items
+from repro.data.baskets import pack_transactions
 from repro.data.sparse import SparseSlab
-from repro.pipeline.dataplane import DataPlane, uniform_tiles
+from repro.pipeline.dataplane import DataPlane, device_tiles
 from repro.pipeline.devgen import DeviceLattice
 from repro.pipeline.report import PipelineReport, RoundReport
 from repro.runtime import (MeasuredPhase, Runtime, SlabPool, SwitchingPolicy,
@@ -51,14 +51,30 @@ from repro.runtime import (MeasuredPhase, Runtime, SlabPool, SwitchingPolicy,
 Baskets = Union[np.ndarray, SparseSlab, Sequence[Sequence[int]]]
 
 
-def ingest_baskets(baskets: Baskets) -> Tuple[np.ndarray, int, int]:
-    """Validate + pack baskets into the kernel bitmap layout.
+def _is_binary(b: np.ndarray) -> bool:
+    """Whether every entry is 0 or 1, in as few passes as the dtype allows:
+    none for ``bool``, one ``max`` for unsigned integers, ``min`` and
+    ``max`` for signed ones, and the elementwise check for anything else
+    (floats, objects), where a reduction would pass 0.5."""
+    kind = b.dtype.kind
+    if kind == "b":
+        return True
+    if kind == "u":
+        return bool(b.max() <= 1)
+    if kind == "i":
+        return bool(b.min() >= 0 and b.max() <= 1)
+    return bool(((b == 0) | (b == 1)).all())
 
-    Returns ``(lane-padded bitmap, raw item count, raw tx count)``.  Shared
-    by the single-device pipeline and the sharded miner so both planes agree
-    byte-for-byte on what they mine.  A :class:`SparseSlab` densifies here
-    *explicitly* — the horizontal (Apriori) formulation needs the dense
-    bitmap; the Eclat plane columnizes the slab without it.
+
+def ingest_baskets(baskets: Baskets) -> Tuple[np.ndarray, int, int]:
+    """Validate + pack baskets into the raw 0/1 ``uint8`` bitmap.
+
+    Returns ``(bitmap [n_tx, n_items], raw item count, raw tx count)``,
+    the item axis not yet padded: the pipeline pads and tiles it on the
+    device, the sharded plane pads it with ``pad_items``.  A
+    :class:`SparseSlab` densifies here *explicitly* — the horizontal
+    (Apriori) formulation needs the dense bitmap; the Eclat plane
+    columnizes the slab without it.
     """
     if isinstance(baskets, SparseSlab):
         baskets = baskets.to_dense()
@@ -68,13 +84,14 @@ def ingest_baskets(baskets: Baskets) -> Tuple[np.ndarray, int, int]:
         # validate BEFORE the uint8 cast: casting would truncate floats
         # (0.9 -> 0) and wrap negatives, hiding bad input behind an
         # empty-but-plausible mining result
-        if baskets.size and not ((baskets == 0) | (baskets == 1)).all():
+        if baskets.size and not _is_binary(baskets):
             raise ValueError("bitmap must contain only 0/1 — pass "
                              "transaction lists for count-style data")
-        T = baskets.astype(np.uint8, copy=False)
+        T = (baskets.view(np.uint8) if baskets.dtype == np.bool_
+             else baskets.astype(np.uint8, copy=False))
     else:
         T = pack_transactions(baskets)
-    return pad_items(T), T.shape[1], T.shape[0]
+    return T, T.shape[1], T.shape[0]
 
 
 @dataclass(frozen=True)
@@ -196,35 +213,30 @@ class MarketBasketPipeline:
     # phases
     # ------------------------------------------------------------------
     def _ingest(self, baskets: Baskets) -> Tuple[np.ndarray, int, int]:
-        """Returns (lane-padded bitmap, raw item count, raw tx count)."""
+        """Returns (raw bitmap, raw item count, raw tx count)."""
         return ingest_baskets(baskets)
 
     def _stage(self, baskets: Baskets):
         """The mine's first two serial phases.  ``mba-ingest`` validates
-        and packs the baskets into the lane-padded bitmap and cuts its row
-        tiles; ``mba-upload`` stages the tiles on the device once — every
-        round's map phase reuses them, so uploading per round would redo
-        the same transfers — and carries their bytes on its record.
-        Returns ``(device tiles, padded bitmap shape, raw item count, raw
-        tx count)``."""
+        and packs the baskets into the raw bitmap; ``mba-upload`` sends its
+        flat bytes to the device once and builds the lane-padded row tiles
+        there (``device_tiles``) — every round's map phase reuses them, so
+        uploading per round would redo the same transfers — and carries
+        the bitmap's bytes on its record.  Returns ``(device tiles,
+        lane-padded bitmap shape, raw item count, raw tx count)``."""
         cfg, rt = self.config, self.runtime
         n_rows = (baskets.n_tx if isinstance(baskets, SparseSlab)
                   else len(baskets))
-
-        def ingest():
-            T, n_items_raw, n_tx_raw = self._ingest(baskets)
-            return (T.shape, n_items_raw, n_tx_raw,
-                    uniform_tiles(T, cfg.n_tiles))
-
-        (shape, n_items_raw, n_tx_raw, host_tiles), _ = rt.run_serial(
+        (T, n_items_raw, n_tx_raw), _ = rt.run_serial(
             "mba-ingest", cost=max(1.0, n_rows * cfg.serial_unit_cost),
-            fn=ingest, min_speed=cfg.serial_min_speed)
-        tiles, _ = rt.run_serial(
-            "mba-upload",
-            cost=max(1.0, float(sum(t.nbytes for t in host_tiles))),
-            fn=lambda: [rt.meter.h2d(t) for t in host_tiles],
+            fn=lambda: self._ingest(baskets),
             min_speed=cfg.serial_min_speed)
-        return tiles, shape, n_items_raw, n_tx_raw
+        tiles, _ = rt.run_serial(
+            "mba-upload", cost=max(1.0, float(T.nbytes)),
+            fn=lambda: list(device_tiles(rt.meter.h2d(T.reshape(-1)),
+                                         T.shape, cfg.n_tiles)),
+            min_speed=cfg.serial_min_speed)
+        return tiles, (n_tx_raw, tiles[0].shape[1]), n_items_raw, n_tx_raw
 
     def _map_round(self, job: MapReduceJob, tiles: List,
                    failures: Optional[List[FailureEvent]],

@@ -137,7 +137,7 @@ class SlidingWindow:
         """The window contents in arrival order, lane-padded.
 
         This is byte-for-byte what a one-shot pipeline over "the same
-        window" ingests (``ingest_baskets`` pads the same way), which is
+        window" mines (it pads the item axis the same way), which is
         what the parity smoke compares against.
         """
         if not self._rows:

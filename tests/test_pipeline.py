@@ -141,6 +141,51 @@ def test_non_binary_bitmap_rejected_before_cast():
         pipe.run(np.ones(8, np.uint8))                # 1-D
 
 
+@pytest.mark.parametrize("bad, match", [
+    (np.array([[2, 0], [0, 1]], np.uint8), "only 0/1"),
+    (np.array([[-1, 0], [0, 1]], np.int8), "only 0/1"),
+    (np.array([[2, 0], [0, 1]], np.int64), "only 0/1"),
+    (np.array([[0.9, 0.0], [0.9, 0.9]]), "only 0/1"),
+    (np.ones(8, np.uint8), "2-D"),
+], ids=["uint8-2", "int8-neg", "int64-2", "float-0.9", "1-D"])
+def test_non_binary_bitmap_rejected_by_dtype(bad, match):
+    """Each dtype's one-pass check rejects what the elementwise check did,
+    with the same message."""
+    pipe = MarketBasketPipeline(config=PipelineConfig(min_support=0.2,
+                                                      n_tiles=2))
+    with pytest.raises(ValueError, match=match):
+        pipe.run(bad)
+
+
+@pytest.mark.parametrize("dtype", [np.bool_, np.int64, np.float32])
+def test_binary_bitmap_of_any_dtype_mines_alike(dtype):
+    T = small_db(seed=13)
+    cfg = PipelineConfig(min_support=0.05, n_tiles=4)
+    want = MarketBasketPipeline(config=cfg).run(T)
+    got = MarketBasketPipeline(config=cfg).run(T.astype(dtype))
+    assert got.supports == want.supports and got.rules == want.rules
+
+
+@pytest.mark.parametrize("n_items", [24, 128, 1000])
+@pytest.mark.parametrize("n_tx, n_tiles", [(100, 32), (999, 32), (7, 32),
+                                           (96, 4)])
+def test_device_tiles_match_host_tiling(n_items, n_tx, n_tiles):
+    """The device-built tiles are byte for byte the host tiling of the
+    lane-padded bitmap: same count, shapes and dtype (7 rows in 32 tiles
+    clamps the count to the rows)."""
+    import jax.numpy as jnp
+    from repro.data.baskets import pad_items
+    from repro.pipeline.dataplane import device_tiles, uniform_tiles
+    rng = np.random.default_rng(n_items * 1000 + n_tx)
+    T = (rng.random((n_tx, n_items)) < 0.1).astype(np.uint8)
+    want = uniform_tiles(pad_items(T), n_tiles)
+    got = device_tiles(jnp.asarray(T.reshape(-1)), T.shape, n_tiles)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), w)
+
+
 def test_failure_energy_bills_replanned_core_as_active():
     """A planned-idle core that executes orphaned tiles must be charged
     active watts, and the dead core gated watts (zero busy seconds)."""

@@ -44,11 +44,12 @@ def test_two_round_mine_transfer_ledger_is_exact():
     m_cap = rounds[1].m_padded
     f2 = rounds[1].n_frequent
 
-    # the one-time tile upload (256 uint8 rows) is its own phase; ingest
-    # before it moves nothing across the boundary
+    # the one-time upload of the raw bitmap (256 uint8 rows of 24 items;
+    # the device pads the lanes) is its own phase; ingest before it moves
+    # nothing across the boundary
     ing, up = by_name["mba-ingest"], by_name["mba-upload"]
     assert ing.h2d_bytes == ing.d2h_bytes == ing.syncs == 0
-    assert up.h2d_bytes == 256 * n_items_pad
+    assert up.h2d_bytes == 256 * 24
     assert up.d2h_bytes == 0 and up.syncs == 0
 
     # round 1: no upload; the single readback is the padded int64

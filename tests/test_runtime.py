@@ -361,8 +361,9 @@ def test_every_mine_phase_is_timed_and_tiles_land_on_upload(
         [(p.name, p.host_time_s) for p in phases if p.host_time_s <= 0]
     # the phases lie inside the mine's own wall
     assert sum(p.host_time_s for p in phases) <= res.report.wall_time_s
-    # the one-time tile upload: 4 tiles of 64 rows x 128 lane-padded items
-    assert phases[1].h2d_bytes == 256 * 128
+    # the one-time upload: the raw 256 x 24 bitmap, which the device pads
+    # and cuts into 4 tiles of 64 rows x 128 lanes
+    assert phases[1].h2d_bytes == 256 * 24
     assert phases[0].h2d_bytes == phases[2].h2d_bytes == 0
     # ingest and upload are priced like every other serial phase
     assert all(p.kind == "serial" and p.sim_time_s > 0 and p.energy_j > 0
@@ -401,3 +402,17 @@ def test_steady_mine_lowers_nothing():
     # pooled count slabs the first left behind, a first op per slab shape
     runs = [pipe.run(T) for _ in range(3)]
     assert sum(p.lowerings for p in runs[2].report.ledger.phases) == 0
+
+
+def test_second_mine_stages_without_lowering():
+    """A repeat mine of the same bitmap shape reuses the device tiling
+    program: nothing lowers in ingest or upload."""
+    from repro.data.baskets import BasketConfig, generate_baskets
+    from repro.pipeline import MarketBasketPipeline, PipelineConfig
+    T = generate_baskets(BasketConfig(n_tx=200, n_items=40, seed=4))
+    pipe = MarketBasketPipeline(config=PipelineConfig(
+        min_support=0.05, n_tiles=4, data_plane="ref"))
+    pipe.run(T)
+    staged = {p.name: p.lowerings for p in pipe.run(T).report.ledger.phases
+              if p.name in ("mba-ingest", "mba-upload")}
+    assert staged == {"mba-ingest": 0, "mba-upload": 0}
