@@ -12,7 +12,7 @@ paper's "single-threaded task", which the MB Scheduler routes to one core
 while gating the rest (power model hook).
 
 ``apriori`` below is the minimal reference driver (used by the property
-tests and B1 bench); the production path with full scheduling/energy
+tests); the production path with full scheduling/energy
 accounting, data-plane batching and rule extraction is
 ``repro.pipeline.MarketBasketPipeline``, which shares this module's
 candidate generation.  Behavioral changes to round semantics belong in

@@ -51,7 +51,7 @@ def stationary_baskets(n_tx: int, n_items: int, n_patterns: int = 6,
     ``window / n_patterns``, noise ≈ ``window / n_items``).  Under such a
     stream the frequent-set lattice is stable across micro-batches and the
     streaming miner's delta path never needs a full re-validation — the
-    steady state the B10 benchmark measures.  ``generate_baskets`` with its
+    steady state where delta maintenance beats re-mining.  ``generate_baskets`` with its
     Zipf noise is the opposite regime: many itemsets hover at the
     threshold and cross it every batch.
     """

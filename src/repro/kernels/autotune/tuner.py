@@ -192,8 +192,8 @@ def tune(kernel: str, shape: Tuple[int, ...], *,
 def standard_shapes(kernel: str, smoke: bool = False
                     ) -> List[Tuple[int, int, int]]:
     """The sweep lattice: one shape per bucket the planes actually hit
-    (B6 tiles 64-1024 rows x 128-2048 candidates; B7 buckets 1-64
-    queries x 128-512 index rows), nearest-bucket lookup covers the
+    (mining tiles 64-1024 rows x 128-2048 candidates; serving buckets
+    1-64 queries x 128-512 index rows), nearest-bucket lookup covers the
     rest.  ``smoke`` shrinks to one tiny shape for the CI sweep leg.
 
     support_count also sweeps the tiles a 100,000 x 1,000 Quest corpus

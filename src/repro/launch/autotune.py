@@ -11,7 +11,7 @@ argmin, so a cache entry is both the fastest and a correct configuration
 for its (kernel, shape-bucket, device kind).  The default ``--out`` is
 the checked-in cache the ops wrappers read
 (:data:`repro.kernels.autotune.cache.DEFAULT_CACHE_PATH`) — refresh it on
-the device class the benchmarks run on.
+the device class ``bench/run.py`` measures on.
 
 ``--smoke`` sweeps one small shape per kernel with 2 candidate configs
 and writes to a scratch path by default: it exists to exercise the whole

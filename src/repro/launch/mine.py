@@ -39,7 +39,6 @@ drives exactly that sequence.
 """
 from __future__ import annotations
 
-import contextlib
 import os
 import tempfile
 
@@ -66,8 +65,7 @@ def mine(n_tx: int = 8192, n_items: int = 128, min_support: float = 0.02,
          seed: int = 0, top: int = 15, sharded: bool = False,
          n_shards: int = 0, smoke: bool = False, policy: str = "static",
          autotune: bool = True, algorithm: str = "apriori",
-         dataset: str = "dense", round_execution: str = "pipelined",
-         profile_dir: str = "", out_of_core: bool = False,
+         dataset: str = "dense", out_of_core: bool = False,
          partition_rows: int = 4096, son_dir: str = "", resume: bool = False,
          kill_after: int = 0):
     if smoke:                       # CI-sized: parity is the point, not scale
@@ -80,16 +78,8 @@ def mine(n_tx: int = 8192, n_items: int = 128, min_support: float = 0.02,
                             min_confidence=min_confidence,
                             n_tiles=n_tiles, policy=policy, split=split,
                             data_plane=data_plane, autotune=autotune,
-                            algorithm=algorithm,
-                            round_execution=round_execution)
+                            algorithm=algorithm)
     choice = None
-    if profile_dir:
-        # one device-level trace of the whole mine (dispatch overlap, the
-        # single d2h per round) — view with tensorboard or Perfetto
-        import jax
-        trace_ctx = jax.profiler.trace(profile_dir)
-    else:
-        trace_ctx = contextlib.nullcontext()
 
     if out_of_core:
         from repro.mining import SONConfig, SONKilled, SONMiner, make_miner
@@ -109,8 +99,7 @@ def mine(n_tx: int = 8192, n_items: int = 128, min_support: float = 0.02,
         else:
             miner, _ = make_miner(T, profile=profile, config=config, son=son)
         try:
-            with trace_ctx:
-                result = miner.run(T)
+            result = miner.run(T)
         except SONKilled as e:
             print(f"[mine] killed at partition boundary {e.boundary} "
                   f"(checkpoint saved under {workdir}) — rerun with "
@@ -128,8 +117,7 @@ def mine(n_tx: int = 8192, n_items: int = 128, min_support: float = 0.02,
               f"split={split} algorithm={algorithm}")
         miner = ShardedMiner(mesh=mesh, profile=profile, config=config,
                              verify_rounds=smoke)
-        with trace_ctx:
-            result = miner.run(T)
+        result = miner.run(T)
         choice = miner.algorithm_choice
     else:
         from repro.mining import make_miner
@@ -137,8 +125,7 @@ def mine(n_tx: int = 8192, n_items: int = 128, min_support: float = 0.02,
         print(f"[mine] profile={profile_name} speeds={profile.speeds.tolist()} "
               f"policy={policy} split={split} algorithm={algorithm}")
         miner, choice = make_miner(T, profile=profile, config=config)
-        with trace_ctx:
-            result = miner.run(T)
+        result = miner.run(T)
 
     if choice is not None:
         print(f"[mine] {choice.summary()}")
@@ -185,14 +172,6 @@ def main():
                          "low-frequency corpus via the CSR slab (the Eclat "
                          "path never builds the dense bitmap)")
     ap.add_argument("--n-tiles", type=int, default=32)
-    ap.add_argument("--round-execution", default="pipelined",
-                    choices=["pipelined", "per_tile"],
-                    help="pipelined = async tile dispatch, donated slabs, "
-                         "one d2h per counting round; per_tile = legacy "
-                         "host readback per tile")
-    ap.add_argument("--profile-dir", default="",
-                    help="write a jax.profiler device trace of the mine "
-                         "here (tensorboard/Perfetto format)")
     ap.add_argument("--sharded", action="store_true",
                     help="execute on the distributed mining plane (shard_map)")
     ap.add_argument("--n-shards", type=int, default=0,
@@ -227,8 +206,7 @@ def main():
          sharded=args.sharded, n_shards=args.n_shards, smoke=args.smoke,
          policy=args.policy, autotune=args.autotune,
          algorithm=args.algorithm, dataset=args.dataset,
-         round_execution=args.round_execution,
-         profile_dir=args.profile_dir, out_of_core=args.out_of_core,
+         out_of_core=args.out_of_core,
          partition_rows=args.partition_rows, son_dir=args.son_dir,
          resume=args.resume, kill_after=args.kill_after)
 

@@ -14,7 +14,7 @@ because ``generate_candidates`` builds each k-candidate by joining two
 tidset is exactly the AND of two rows the previous level already
 materialized.  The transaction axis is paid for once at columnization;
 each round then touches ``candidates × n_tx/32`` words instead of
-``n_tx × n_items`` lanes, which is why Eclat wins on dense data (B11).
+``n_tx × n_items`` lanes, which is why Eclat wins on dense data.
 
 Everything around the formulation is deliberately identical to the
 Apriori plane: same ``generate_candidates``/``generate_rules`` control
@@ -96,7 +96,7 @@ class EclatMiner:
         self.backend = resolve_backend(cfg.data_plane)
         self.interpret = cfg.interpret
         self.tuning = None if cfg.autotune else False
-        # round-persistent donated count accumulators (pipelined rounds)
+        # round-persistent donated count accumulators
         self.slabs = SlabPool()
 
     # ------------------------------------------------------------------
@@ -145,56 +145,33 @@ class EclatMiner:
                    m_true: int, failures: Optional[List[FailureEvent]]):
         """One tiled intersection phase through the shared runtime.
 
-        Pipelined (default): every tile scatters its device counts into a
-        tile-offset window of an [m_pad] vector, partials fold into a
-        donated slab accumulator, and the round reads back one sliced
-        vector — one sync.  ``per_tile`` keeps the legacy readback per
-        tile (the B13 baseline)."""
+        Every tile scatters its device counts into a tile-offset window of
+        an [m_pad] vector, partials fold into a donated slab accumulator,
+        and the round reads back one sliced vector — one sync."""
         tiles = self._pair_tiles(A, B)
         n_words = A.shape[1]
         meter = self.runtime.meter
-        pipelined = self.config.round_execution == "pipelined"
+        rows = int(tiles[0][1].shape[0])
+        m_pad = rows * len(tiles)
 
-        if pipelined:
-            rows = int(tiles[0][1].shape[0])
-            m_pad = rows * len(tiles)
+        def map_fn(tile):
+            off, Aj, Bj = tile
+            return (jnp.zeros(m_pad, jnp.int32)
+                    .at[off:off + rows]
+                    .set(self._count(Aj, Bj).astype(jnp.int32)))
 
-            def map_fn(tile):
-                off, Aj, Bj = tile
-                return (jnp.zeros(m_pad, jnp.int32)
-                        .at[off:off + rows]
-                        .set(self._count(Aj, Bj).astype(jnp.int32)))
+        def finalize(acc):
+            out = meter.d2h(acc[:m_true], dtype=np.int64)
+            self.slabs.give(acc)
+            return out
 
-            def finalize(acc):
-                out = meter.d2h(acc[:m_true], dtype=np.int64)
-                self.slabs.give(acc)
-                return out
-
-            job = MapReduceJob(
-                name=name,
-                map_fn=map_fn,
-                combine_fn=donated_add,
-                zero_fn=lambda: self.slabs.take((m_pad,), jnp.int32),
-                cost_fn=lambda t: float(t[1].nbytes + t[2].nbytes),
-            )
-        else:
-            finalize = None
-
-            def tile_counts(tile) -> np.ndarray:
-                off, Aj, Bj = tile
-                counts = meter.d2h(self._count(Aj, Bj), dtype=np.int64)
-                out = np.zeros(m_true, dtype=np.int64)
-                seg = counts[:max(0, min(len(counts), m_true - off))]
-                out[off:off + len(seg)] = seg
-                return out
-
-            job = MapReduceJob(
-                name=name,
-                map_fn=tile_counts,
-                combine_fn=lambda a, b: a + b,  # disjoint segments
-                zero_fn=lambda m=m_true: np.zeros(m, dtype=np.int64),
-                cost_fn=lambda t: float(t[1].nbytes + t[2].nbytes),
-            )
+        job = MapReduceJob(
+            name=name,
+            map_fn=map_fn,
+            combine_fn=donated_add,
+            zero_fn=lambda: self.slabs.take((m_pad,), jnp.int32),
+            cost_fn=lambda t: float(t[1].nbytes + t[2].nbytes),
+        )
         tile_costs = np.array([job.tile_cost(t) for t in tiles],
                               dtype=np.float64)
         tile_rows = np.array([t[1].shape[0] for t in tiles], dtype=np.float64)
@@ -207,8 +184,7 @@ class EclatMiner:
             result, rep = self.cluster.run(job, tiles, failures=failures,
                                            speculate=self.config.speculate,
                                            assignment=asg)
-            if finalize is not None:
-                result = finalize(result)   # the round's single sync
+            result = finalize(result)       # the round's single sync
             return MeasuredPhase(result=result, busy_s=rep.busy_s,
                                  makespan=rep.makespan,
                                  switches=rep.switches, reissued=rep.reissued,

@@ -101,8 +101,9 @@ def device_tiles(flat: jax.Array, shape: Tuple[int, int],
 class DataPlane:
     """Per-level candidate batch + per-tile support counting.
 
-    Usage: ``prepare(C)`` once per Apriori level, then ``tile_counts(tile)``
-    for every transaction tile (this is the MapReduceJob's map_fn).
+    Usage: ``prepare(C)`` (host bitmap) or ``prepare_device(C)`` once per
+    Apriori level, then ``tile_counts_device(tile)`` for every transaction
+    tile (this is the MapReduceJob's map_fn).
     """
 
     def __init__(self, kind: str = "auto", m_bucket: int = 128,
@@ -121,7 +122,6 @@ class DataPlane:
         # owning Runtime's ledger can attribute them per phase
         self.meter = meter if meter is not None else METER
         self._C: Optional[jnp.ndarray] = None
-        self._m_true = 0
 
     @property
     def m_padded(self) -> int:
@@ -130,18 +130,16 @@ class DataPlane:
     # ------------------------------------------------------------------
     def prepare(self, C: np.ndarray) -> None:
         """Stage a level's candidate bitmap (padded to the bucket shape)."""
-        self._m_true = C.shape[0]
         self._C = self.meter.h2d(pad_candidates(C, self.m_bucket))
 
     def prepare_device(self, C: jnp.ndarray) -> None:
-        """Stage an already-device-resident candidate bitmap (the
-        pipelined path: padding rows are zeroed, so no re-pad and no
-        transfer — the generator built it in place)."""
+        """Stage an already-device-resident candidate bitmap (from the
+        device candidate generator: padding rows are zeroed, so no re-pad
+        and no transfer — the generator built it in place)."""
         if C.shape[0] % self.m_bucket:
             raise ValueError(
                 f"device candidate bitmap rows {C.shape[0]} not a multiple "
                 f"of m_bucket={self.m_bucket}")
-        self._m_true = int(C.shape[0])
         self._C = C
 
     def _counts(self, tile) -> jnp.ndarray:
@@ -151,19 +149,9 @@ class DataPlane:
                                  tuning=self.tuning)
         return _jitted_ref(Tj, self._C)
 
-    def tile_counts(self, tile: np.ndarray) -> np.ndarray:
-        """Support counts [m_true] int64 for one transaction tile.
-
-        The per-tile readback is a device sync: launches serialize on it,
-        which is exactly what ``round_execution="per_tile"`` measures.
-        """
-        assert self._C is not None, "prepare() before tile_counts()"
-        return self.meter.d2h(self._counts(tile)[:self._m_true],
-                              dtype=np.int64)
-
     def tile_counts_device(self, tile) -> jnp.ndarray:
         """Device-resident counts [m_padded] int32 for one tile — no slice,
-        no readback, no sync: the pipelined round combines these on device
+        no readback, no sync: the round combines these on device
         and reads one packed vector back at round close."""
         assert self._C is not None, "prepare() before tile_counts_device()"
         return self._counts(tile)

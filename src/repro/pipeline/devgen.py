@@ -13,7 +13,7 @@ functions, so a round's only host crossing is one packed count vector:
   ``_finalize``   count accumulator ──▶ packed [m_cap+1] int32 vector:
                   per-candidate counts (−1 sentinel for padding) and J,
                   the next level's join-pair count — the **one d2h** the
-                  pipelined round makes
+                  round makes
   ``advance()``   host bookkeeping off the packed vector; the compacted
                   frequent matrix stays on device for the next join
 

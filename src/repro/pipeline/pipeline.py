@@ -34,8 +34,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.core.hetero import HeterogeneityProfile
-from repro.core.itemsets import (AprioriResult, frequent_itemsets,
-                                 generate_candidates, itemsets_to_bitmap)
+from repro.core.itemsets import AprioriResult, frequent_itemsets
 from repro.core.mapreduce import FailureEvent, MapReduceJob, SimulatedCluster
 from repro.core.power import PowerModel
 from repro.core.scheduler import MBScheduler, TaskSpec
@@ -109,12 +108,6 @@ class PipelineConfig:
     # model picks per dataset from measured density/sparsity features —
     # see repro.mining.select).  All backends are pinned bit-identical.
     algorithm: str = "apriori"
-    # Round execution: "pipelined" (default) dispatches every tile kernel
-    # eagerly, folds partial counts into a donated device accumulator and
-    # reads back one packed vector per round (single sync point; candidate
-    # generation stays on device — see repro.pipeline.devgen).  "per_tile"
-    # is the legacy sync-per-tile path, kept as the B13 A/B baseline.
-    round_execution: str = "pipelined"
     n_tiles: int = 32
     policy: str = "static"          # switching: static | dynamic | costmodel
     split: str = "lpt"              # tile split: equal | proportional | lpt
@@ -197,10 +190,6 @@ class MarketBasketPipeline:
         self.power = self.runtime.power
         self.cluster = SimulatedCluster(self.profile, self.scheduler,
                                         power=None)  # ledger prices energy
-        if cfg.round_execution not in ("pipelined", "per_tile"):
-            raise ValueError(
-                f"unknown round_execution {cfg.round_execution!r} "
-                "(expected 'pipelined' or 'per_tile')")
         self.data_plane = DataPlane(cfg.data_plane,
                                     m_bucket=cfg.m_bucket,
                                     interpret=cfg.interpret,
@@ -245,9 +234,9 @@ class MarketBasketPipeline:
         """One tiled map phase through the shared runtime: the policy plans
         the assignment, the simulated cluster executes it, the runtime does
         the time/energy/switch accounting exactly once.  ``finalize`` runs
-        on the combined result *inside* the phase — the pipelined path's
-        single d2h readback happens there, so the sync lands on this
-        phase's ledger record, not the next one's."""
+        on the combined result *inside* the phase — the round's single d2h
+        readback happens there, so the sync lands on this phase's ledger
+        record, not the next one's."""
         tile_costs = np.array([job.tile_cost(t) for t in tiles],
                               dtype=np.float64)
         # one family: every round maps the same device-resident tiles, so
@@ -273,120 +262,9 @@ class MarketBasketPipeline:
     # ------------------------------------------------------------------
     def run(self, baskets: Baskets,
             failures: Optional[List[FailureEvent]] = None) -> PipelineResult:
-        if self.config.round_execution == "pipelined":
-            return self._run_pipelined(baskets, failures)
-        return self._run_per_tile(baskets, failures)
-
-    # ------------------------------------------------------------------
-    # legacy sync-per-tile rounds — the B13 A/B baseline
-    # ------------------------------------------------------------------
-    def _run_per_tile(self, baskets: Baskets,
-                      failures: Optional[List[FailureEvent]] = None
-                      ) -> PipelineResult:
-        cfg = self.config
-        rt = self.runtime
-        t_start = time.perf_counter()
-        # a run that raised mid-way (invariant check, scoring error) leaves
-        # orphaned records; this plane owns its runtime, so anything still
-        # live belongs to no report — drop it before marking
-        rt.ledger.take_since(0)
-        mark = rt.ledger.mark()
-
-        # n_items is the lane-padded (internal) width
-        tiles, (_, n_items), n_items_raw, n_tx_raw = self._stage(baskets)
-        min_sup = cfg.abs_support(n_tx_raw)
-        tile_rows = np.array([t.shape[0] for t in tiles], dtype=np.float64)
-
-        report = PipelineReport(
-            backend=self.data_plane.backend, policy=rt.policy.name,
-            split=rt.split,
-            profile_speeds=[float(s) for s in self.profile.speeds],
-            n_tx=n_tx_raw, n_items=n_items_raw,
-            n_tiles=len(tiles), min_support=min_sup)
-        supports: Dict[Tuple[int, ...], int] = {}
-
-        # ---- round k=1: item frequency (<item, count>) ----------------
-        job1 = MapReduceJob(
-            name="mba-round1-item-counts",
-            # sum on device, transfer n_items ints — not the whole tile back
-            # (still one readback *per tile*: that sync is this path's
-            # defining cost, which the pipelined path removes)
-            map_fn=lambda tile: rt.meter.d2h(
-                tile.sum(axis=0, dtype=jnp.int32), dtype=np.int64),
-            combine_fn=lambda a, b: a + b,
-            zero_fn=lambda: np.zeros(n_items, dtype=np.int64),
-        )
-        counts, rec = self._map_round(job1, tiles, failures,
-                                      tile_flops=tile_rows * n_items)
-        frequent = [(int(i),) for i in np.nonzero(counts >= min_sup)[0]]
-        for (i,) in frequent:
-            supports[(i,)] = int(counts[i])
-        report.rounds.append(RoundReport.from_phases(
-            k=1, n_candidates=n_items_raw, n_frequent=len(frequent),
-            map_phase=rec))
-
-        # ---- rounds k>=2: serial candidate-gen + tiled counting -------
-        k = 2
-        while frequent and (cfg.max_k == 0 or k <= cfg.max_k):
-            cands, serial = rt.run_serial(
-                f"mba-candgen-k{k}",
-                cost=candgen_cost(len(frequent), k, cfg.serial_unit_cost),
-                fn=lambda fr=frequent: generate_candidates(fr),
-                min_speed=cfg.serial_min_speed)
-            if not cands:
-                report.rounds.append(RoundReport.from_phases(
-                    k=k, n_candidates=0, n_frequent=0, map_phase=None,
-                    serial=serial, n_devices=self.profile.n))
-                break
-
-            self.data_plane.prepare(itemsets_to_bitmap(cands, n_items))
-            job = MapReduceJob(
-                name=f"mba-round{k}-support",
-                map_fn=self.data_plane.tile_counts,
-                combine_fn=lambda a, b: a + b,
-                zero_fn=lambda m=len(cands): np.zeros(m, dtype=np.int64),
-            )
-            m_padded = self.data_plane.m_padded
-            sup, rec = self._map_round(
-                job, tiles, failures,
-                tile_flops=support_flops(tile_rows, n_items, m_padded))
-            frequent = []
-            for c, s in zip(cands, sup):
-                if s >= min_sup:
-                    supports[c] = int(s)
-                    frequent.append(c)
-            report.rounds.append(RoundReport.from_phases(
-                k=k, n_candidates=len(cands), n_frequent=len(frequent),
-                map_phase=rec, serial=serial, m_padded=m_padded))
-            k += 1
-
-        # ---- step 3: association rules (serial control plane) ---------
-        rules, rules_rec = rt.run_serial(
-            "mba-rules",
-            cost=max(1.0, len(supports) * cfg.serial_unit_cost),
-            fn=lambda: generate_rules(
-                AprioriResult(supports=supports, n_tx=n_tx_raw, levels=k - 1),
-                cfg.min_confidence, min_lift=cfg.min_lift),
-            min_speed=cfg.serial_min_speed)
-        report.rules_phase = rules_rec
-
-        report.n_itemsets = len(supports)
-        report.n_rules = len(rules)
-        report.wall_time_s = time.perf_counter() - t_start
-        report.ledger = rt.ledger.take_since(mark)
-        return PipelineResult(supports=supports, rules=rules, report=report,
-                              n_tx=n_tx_raw)
-
-    # ------------------------------------------------------------------
-    # pipelined device-resident rounds (the default)
-    # ------------------------------------------------------------------
-    def _run_pipelined(self, baskets: Baskets,
-                       failures: Optional[List[FailureEvent]] = None
-                       ) -> PipelineResult:
-        """Same mining semantics as :meth:`_run_per_tile`, with rounds held
-        on device: all tile kernels of a round dispatch eagerly (nothing in
-        the map fan-out synchronizes), partial counts fold into a donated
-        slab accumulator, candidate generation for the next level runs as a
+        """Mine ``baskets`` with every counting round held on device: all tile
+        kernels of a round dispatch eagerly (nothing in the map fan-out
+        synchronizes), partial counts fold into a donated slab accumulator, candidate generation for the next level runs as a
         jitted join on the compacted frequent matrix, and the only
         device→host crossing per counting round is one packed
         ``[m_cap + 1]`` vector (counts + next join size) read inside the
@@ -394,6 +272,9 @@ class MarketBasketPipeline:
         cfg = self.config
         rt = self.runtime
         t_start = time.perf_counter()
+        # a run that raised mid-way (invariant check, scoring error) leaves
+        # orphaned records; this plane owns its runtime, so anything still
+        # live belongs to no report — drop it before marking
         rt.ledger.take_since(0)
         mark = rt.ledger.mark()
 

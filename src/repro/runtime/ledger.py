@@ -64,7 +64,7 @@ class PhaseRecord:
     # host/device data movement attributed to this phase (metered by the
     # Runtime's TransferMeter; staging between phases lands on the phase
     # that consumes it).  ``syncs`` counts device->host synchronization
-    # points — the pipelined round contract is exactly 1 per map round.
+    # points — the round contract is exactly 1 per map round.
     h2d_bytes: int = 0
     d2h_bytes: int = 0
     syncs: int = 0

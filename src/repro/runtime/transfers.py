@@ -1,4 +1,4 @@
-"""Device-transfer accounting — the observability half of pipelined rounds.
+"""Device-transfer accounting — the observability half of device-held rounds.
 
 JAX dispatch is asynchronous: a round of tile kernels costs almost nothing
 to *launch*; what serializes a mining round is every host/device boundary
@@ -8,12 +8,12 @@ is an H2D copy.  The planes therefore route **all** boundary crossings
 through a :class:`TransferMeter`, which makes three quantities exact and
 ledger-attributable per phase:
 
-* ``h2d_bytes`` — bytes staged host → device (tile uploads, candidate
-  slabs on the legacy path, fallback candidate matrices)
+* ``h2d_bytes`` — bytes staged host → device (tile uploads, host-built
+  candidate bitmaps, fallback candidate matrices)
 * ``d2h_bytes`` — bytes read back device → host (one packed count vector
-  per round on the pipelined path; per-tile vectors on the legacy path)
+  per counting round)
 * ``syncs``     — device→host synchronization points (each ``d2h`` is one;
-  the pipelined round contract is **exactly one per counting round**)
+  the round contract is **exactly one per counting round**)
 
 :class:`repro.runtime.Runtime` snapshots its meter after every phase, so
 each :class:`~repro.runtime.ledger.PhaseRecord` carries the transfers that

@@ -7,7 +7,7 @@ recommendation lists, so a hit skips the kernel entirely.
 
 Hit/miss counters are cumulative for the cache's lifetime; the engine
 reports per-``serve`` deltas.  ``maxsize=0`` disables caching (every
-lookup is a miss), which is the "cache off" arm of the B7 benchmark.
+lookup is a miss), the "cache off" baseline.
 The engine clears the cache on index ``refresh()`` — entries computed
 against a stale index must never be served.
 """

@@ -93,7 +93,6 @@ class StreamingConfig:
     min_lift: float = 0.0
     max_k: int = 0                  # 0 = mine until no candidates survive
     n_tiles: int = 8                # validation-pass map tiles
-    round_execution: str = "pipelined"  # pipelined | per_tile (see PipelineConfig)
     policy: str = "static"          # switching: static | dynamic | costmodel
     split: str = "lpt"              # tile split: equal | proportional | lpt
     data_plane: str = "auto"        # auto | pallas | ref
@@ -115,7 +114,6 @@ class StreamingConfig:
                   min_confidence=self.min_confidence,
                   min_lift=self.min_lift, max_k=self.max_k,
                   n_tiles=self.n_tiles,
-                  round_execution=self.round_execution,
                   policy=self.policy, split=self.split,
                   data_plane=self.data_plane, m_bucket=self.m_bucket,
                   interpret=self.interpret, autotune=self.autotune,
@@ -247,10 +245,6 @@ class StreamingMiner:
         self.profile = profile or HeterogeneityProfile.paper()
         self.config = config or StreamingConfig()
         cfg = self.config
-        if cfg.round_execution not in ("pipelined", "per_tile"):
-            raise ValueError(
-                f"round_execution must be 'pipelined' or 'per_tile', "
-                f"got {cfg.round_execution!r}")
         policy = policy if policy is not None else cfg.policy
         if policy == "costmodel" and cfg.autotune:
             # measured kernel walls replace the datasheet constants
@@ -338,22 +332,11 @@ class StreamingMiner:
                         n_tiles=len(slabs), family="stream-delta")
 
         meter = self.runtime.meter
-        pipelined = self.config.round_execution == "pipelined"
 
         def execute(_asg, _costs):
-            if not pipelined:           # legacy: host math + per-slab syncs
-                d_items = (arrived.sum(axis=0, dtype=np.int64)
-                           - evicted.sum(axis=0, dtype=np.int64))
-                d_supp = np.zeros(len(self._tracked), dtype=np.int64)
-                if self._tracked:
-                    if arrived.shape[0]:
-                        d_supp += self.data_plane.tile_counts(arrived)
-                    if evicted.shape[0]:
-                        d_supp -= self.data_plane.tile_counts(evicted)
-                return MeasuredPhase(result=(d_items, d_supp))
-            # pipelined: both slabs' item deltas and tracked-support deltas
-            # compute on device; one packed [Ip + m] readback is the batch's
-            # single sync point
+            # both slabs' item deltas and tracked-support deltas compute on
+            # device; one packed [Ip + m] readback is the batch's single
+            # sync point
             m = len(self._tracked)
             d_items = jnp.zeros(Ip, jnp.int32)
             d_supp = jnp.zeros(m, jnp.int32)
@@ -387,7 +370,6 @@ class StreamingMiner:
         Ip = self.window.n_items_padded
         W = self.window.rows()
         meter = self.runtime.meter
-        pipelined = cfg.round_execution == "pipelined"
         tiles = [meter.h2d(t) for t in uniform_tiles(W, cfg.n_tiles)]
         tile_rows = np.array([t.shape[0] for t in tiles], dtype=np.float64)
 
@@ -418,17 +400,13 @@ class StreamingMiner:
 
             def execute(_asg, _costs, tiles=tiles, m=len(cands),
                         m_pad=m_padded):
-                if pipelined:   # donated device accumulate, one sync/level
-                    acc = self.slabs.take((m_pad,), jnp.int32)
-                    for t in tiles:
-                        acc = donated_add(
-                            acc, self.data_plane.tile_counts_device(t))
-                    counts = meter.d2h(acc[:m], dtype=np.int64)
-                    self.slabs.give(acc)
-                    return MeasuredPhase(result=counts)
-                counts = np.zeros(m, dtype=np.int64)
+                # donated device accumulate, one sync per level
+                acc = self.slabs.take((m_pad,), jnp.int32)
                 for t in tiles:
-                    counts += self.data_plane.tile_counts(t)
+                    acc = donated_add(
+                        acc, self.data_plane.tile_counts_device(t))
+                counts = meter.d2h(acc[:m], dtype=np.int64)
+                self.slabs.give(acc)
                 return MeasuredPhase(result=counts)
 
             counts, _ = self.runtime.run_phase(

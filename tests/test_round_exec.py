@@ -1,21 +1,24 @@
 """Device-resident round execution: the transfer ledger is exact for a
-scripted two-round mine, the pipelined path makes exactly one d2h sync per
-counting round (the per_tile baseline makes one per tile), the on-device
-candidate join/prune matches the host generate_candidates bit for bit
-(including the guarded host fallback), and both execution modes mine
-identical supports/rules on Apriori and Eclat."""
+scripted two-round mine, every counting round of the Apriori, Eclat and
+streaming miners makes exactly one d2h sync, the on-device candidate
+join/prune matches the host generate_candidates bit for bit (including the
+guarded host fallback), and Apriori and Eclat mine the oracle's
+supports/rules under every switching policy."""
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
-from repro.core.itemsets import (apriori_bruteforce, generate_candidates,
-                                 itemsets_to_bitmap)
-from repro.data.baskets import BasketConfig, generate_baskets
+from repro.core.itemsets import (AprioriResult, apriori_bruteforce,
+                                 generate_candidates, itemsets_to_bitmap)
+from repro.core.rules import generate_rules
+from repro.data.baskets import (BasketConfig, generate_baskets,
+                                stationary_baskets)
 from repro.mining import EclatMiner
 from repro.pipeline import MarketBasketPipeline, PipelineConfig
 from repro.pipeline.dataplane import pad_candidates
 from repro.pipeline.devgen import DeviceLattice
+from repro.streaming import StreamingConfig, StreamingMiner, TransactionStream
 
 
 def _mk_cfg(**kw):
@@ -23,6 +26,17 @@ def _mk_cfg(**kw):
                 data_plane="ref")
     base.update(kw)
     return PipelineConfig(**base)
+
+
+def _oracle(T, cfg):
+    """Brute-force supports and the rules derived from them."""
+    n_tx = T.shape[0]
+    supports = apriori_bruteforce(T, cfg.abs_support(n_tx), max_k=8)
+    levels = max(len(c) for c in supports)
+    rules = generate_rules(AprioriResult(supports=supports, n_tx=n_tx,
+                                         levels=levels),
+                           cfg.min_confidence, min_lift=cfg.min_lift)
+    return supports, rules
 
 
 # ---------------------------------------------------------------------------
@@ -88,31 +102,49 @@ def test_two_round_mine_transfer_ledger_is_exact():
 # the one-sync-per-round contract (asserted, not just benched)
 # ---------------------------------------------------------------------------
 
-def test_pipelined_syncs_once_per_round_per_tile_syncs_per_tile():
+def test_apriori_syncs_once_per_round_and_mines_the_oracle():
     T = generate_baskets(BasketConfig(n_tx=512, n_items=32, seed=5))
-    runs = {}
-    for rexec in ("pipelined", "per_tile"):
-        res = MarketBasketPipeline(config=_mk_cfg(round_execution=rexec)
-                                   ).run(T)
-        maps = res.report.ledger.by_kind("map")
-        assert maps, "mine must run at least one counting round"
-        if rexec == "pipelined":
-            assert all(p.syncs == 1 for p in maps), \
-                [(p.name, p.syncs) for p in maps]
-        else:
-            assert all(p.syncs == p.n_tiles == 4 for p in maps), \
-                [(p.name, p.syncs) for p in maps]
-        runs[rexec] = res
-
-    # both modes mine the same answer, and it is the oracle's
-    want = apriori_bruteforce(T, max(1, int(0.05 * 512)), max_k=8)
-    assert runs["pipelined"].supports == runs["per_tile"].supports == want
-    assert runs["pipelined"].rules == runs["per_tile"].rules
+    cfg = _mk_cfg()
+    res = MarketBasketPipeline(config=cfg).run(T)
+    maps = res.report.ledger.by_kind("map")
+    assert len(maps) >= 2, "mine must run at least two counting rounds"
+    assert all(p.syncs == 1 for p in maps), \
+        [(p.name, p.syncs) for p in maps]
+    want_supports, want_rules = _oracle(T, cfg)
+    assert res.supports == want_supports
+    assert res.rules == want_rules
 
 
-def test_round_execution_knob_is_validated():
-    with pytest.raises(ValueError):
-        MarketBasketPipeline(config=_mk_cfg(round_execution="bogus"))
+def test_eclat_round_reads_back_once():
+    T = generate_baskets(BasketConfig(n_tx=512, n_items=32, seed=5))
+    cfg = _mk_cfg(algorithm="eclat")
+    res = EclatMiner(config=cfg).run(T)
+    rounds = [p for p in res.report.ledger.by_kind("map")
+              if p.name.startswith("eclat-round")]
+    assert {p.name for p in rounds} >= {"eclat-round1-item-counts",
+                                        "eclat-round2-intersect"}
+    assert all(p.syncs == 1 for p in rounds), \
+        [(p.name, p.syncs) for p in rounds]
+    assert res.supports == _oracle(T, cfg)[0]
+
+
+def test_streaming_delta_and_validation_read_back_once():
+    # a stationary stream, then a shifted one: deltas on every batch and
+    # re-validations (k >= 2) both at warm-up and after the shift
+    T = np.vstack([stationary_baskets(512, 32, n_patterns=4, seed=5),
+                   stationary_baskets(512, 32, n_patterns=4, seed=6)])
+    cfg = StreamingConfig(window=256, batch_size=64, min_support=0.15,
+                          min_confidence=0.5, n_tiles=4, data_plane="ref",
+                          power="none")
+    report = StreamingMiner(32, config=cfg).run(
+        TransactionStream(T, cfg.batch_size))
+    phases = report.ledger.phases
+    delta = [p for p in phases if p.name.startswith("stream-delta-")]
+    validate = [p for p in phases if p.name.startswith("stream-validate-k")]
+    assert len(delta) == report.n_batches == 16
+    assert len(validate) >= 2 and report.n_revalidations >= 2
+    assert all(p.syncs == 1 for p in delta + validate), \
+        [(p.name, p.syncs) for p in delta + validate if p.syncs != 1]
 
 
 # ---------------------------------------------------------------------------
@@ -168,20 +200,17 @@ def test_device_join_prune_matches_generate_candidates(seed, lat_kw):
 
 
 # ---------------------------------------------------------------------------
-# cross-mode parity on both algorithms
+# oracle parity on both algorithms under both switching policies
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("algorithm", ["apriori", "eclat"])
 @pytest.mark.parametrize("policy", ["static", "dynamic"])
-def test_both_modes_mine_identically(algorithm, policy):
+def test_every_algorithm_and_policy_mines_the_oracle(algorithm, policy):
     T = generate_baskets(BasketConfig(n_tx=384, n_items=28, seed=9))
-    results = []
-    for rexec in ("pipelined", "per_tile"):
-        cfg = _mk_cfg(algorithm=algorithm, policy=policy,
-                      round_execution=rexec)
-        miner = (EclatMiner(config=cfg) if algorithm == "eclat"
-                 else MarketBasketPipeline(config=cfg))
-        results.append(miner.run(T))
-    want = apriori_bruteforce(T, max(1, int(0.05 * 384)), max_k=8)
-    assert results[0].supports == results[1].supports == want
-    assert results[0].rules == results[1].rules
+    cfg = _mk_cfg(algorithm=algorithm, policy=policy)
+    miner = (EclatMiner(config=cfg) if algorithm == "eclat"
+             else MarketBasketPipeline(config=cfg))
+    res = miner.run(T)
+    want_supports, want_rules = _oracle(T, cfg)
+    assert res.supports == want_supports
+    assert res.rules == want_rules

@@ -343,15 +343,12 @@ def test_span_name_drops_only_a_trailing_step_counter(name, span):
     assert span_name(name) == span
 
 
-@pytest.mark.parametrize("round_execution", ["pipelined", "per_tile"])
-def test_every_mine_phase_is_timed_and_tiles_land_on_upload(
-        round_execution):
+def test_every_mine_phase_is_timed_and_tiles_land_on_upload():
     from repro.data.baskets import BasketConfig, generate_baskets
     from repro.pipeline import MarketBasketPipeline, PipelineConfig
     T = generate_baskets(BasketConfig(n_tx=256, n_items=24, seed=3))
     res = MarketBasketPipeline(config=PipelineConfig(
-        min_support=0.05, n_tiles=4, data_plane="ref",
-        round_execution=round_execution)).run(T)
+        min_support=0.05, n_tiles=4, data_plane="ref")).run(T)
     phases = res.report.ledger.phases
     names = [p.name for p in phases]
     assert names[:3] == ["mba-ingest", "mba-upload",
