@@ -35,20 +35,48 @@ from repro.core.scheduler import Assignment, MBScheduler, TaskSpec
 
 @dataclass(frozen=True)
 class MapReduceJob:
-    """map: tile -> value; combine: value × value -> value (associative)."""
+    """map: tile -> value; combine: value × value -> value (associative).
+
+    ``round_fn`` computes the whole round at once, ``tiles -> value``: a
+    job whose tiles stay resident on one device gives it, so a round is one
+    call into the device instead of a host fold over the tiles.  Without
+    it the round is the per-tile fold of ``map_fn`` / ``combine_fn`` from
+    ``zero_fn()``.  Either way every tile is counted exactly once, so the
+    value is the same."""
 
     name: str
-    map_fn: Callable[[Any], Any]
-    combine_fn: Callable[[Any, Any], Any]
-    zero_fn: Callable[[], Any]
+    map_fn: Optional[Callable[[Any], Any]] = None
+    combine_fn: Optional[Callable[[Any, Any], Any]] = None
+    zero_fn: Optional[Callable[[], Any]] = None
     cost_fn: Optional[Callable[[Any], float]] = None   # work units per tile
+    round_fn: Optional[Callable[[Sequence[Any]], Any]] = None
+
+    def __post_init__(self):
+        if self.round_fn is None and None in (self.map_fn, self.combine_fn,
+                                              self.zero_fn):
+            raise ValueError(f"job {self.name!r} needs a round_fn or all of "
+                             f"map_fn, combine_fn and zero_fn")
 
     def tile_cost(self, tile) -> float:
+        """Work units of one tile: ``cost_fn``, else its bytes (an array's
+        ``nbytes``, or ``size`` × item size of a shape-and-dtype spec)."""
         if self.cost_fn is not None:
             return float(self.cost_fn(tile))
         if hasattr(tile, "nbytes"):
             return float(tile.nbytes)
+        if hasattr(tile, "dtype") and hasattr(tile, "size"):
+            return float(tile.size * np.dtype(tile.dtype).itemsize)
         return 1.0
+
+    def run_round(self, tiles: Sequence[Any]) -> Tuple[Any, int]:
+        """The round's value and the number of calls it made: 1 for
+        ``round_fn``, one ``map_fn`` call per tile for the fold."""
+        if self.round_fn is not None:
+            return self.round_fn(tiles), 1
+        result = self.zero_fn()
+        for t in tiles:
+            result = self.combine_fn(result, self.map_fn(t))
+        return result, len(tiles)
 
 
 @dataclass
@@ -64,6 +92,7 @@ class ExecReport:
     tiles_done: Optional[List[int]] = None   # tiles *executed* per device
     # (differs from assignment.tiles_of after failures: orphaned tiles are
     # counted at the survivor that re-ran them)
+    map_calls: int = 0     # host calls the round made (MapReduceJob.run_round)
 
 
 @dataclass
@@ -108,10 +137,8 @@ class SimulatedCluster:
             report.energy_j = self.power.energy(
                 report.busy_s, report.makespan, gated=gated,
                 switches=report.switches + report.reissued)
-        # --- actual computation: every tile exactly once, combiner tree ---
-        result = job.zero_fn()
-        for t in tiles:
-            result = job.combine_fn(result, job.map_fn(t))
+        # --- actual computation: every tile exactly once ---
+        result, report.map_calls = job.run_round(tiles)
         return result, report
 
     # ------------------------------------------------------------------

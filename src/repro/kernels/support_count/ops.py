@@ -16,12 +16,19 @@ the CI baselines hold the packed variant to *beating* the jitted ref.
 """
 from __future__ import annotations
 
+import functools
+import operator
+
+import jax
 import jax.numpy as jnp
 
 from repro.kernels.autotune.cache import dispatch
-from repro.kernels.support_count.fused import support_count_fused
+from repro.kernels.support_count.fused import (pack_words,
+                                               support_count_fused,
+                                               support_count_fused_pallas)
 from repro.kernels.support_count.intersect import intersect_count_pallas
-from repro.kernels.support_count.kernel import support_count_mxu
+from repro.kernels.support_count.kernel import (support_count_mxu,
+                                                support_count_pallas)
 from repro.kernels.support_count.ref import intersect_count_ref, support_count_ref
 
 
@@ -60,20 +67,79 @@ def support_count(T: jnp.ndarray, C: jnp.ndarray, *,
     T = _pad_to(_pad_to(T.astype(jnp.int8), 1, 128), 0, 8)
     C = _pad_to(_pad_to(C.astype(jnp.int8), 1, 128), 0, 128)
     N, I = T.shape
-    M = C.shape[0]
-    cfg, interpret = dispatch("support_count", (N, M, I), tuning, interpret)
-    bn = _fit(cfg.get("bn", 512), N)
-    bm = _fit(cfg.get("bm", 256), M)
-    if cfg.get("variant", "mxu") == "packed":
-        out = support_count_fused(T, C, bn=bn, bm=bm, interpret=interpret)
+    variant, blocks, interpret = _launch(N, C.shape[0], I, tuning, interpret)
+    if variant == "packed":
+        out = support_count_fused(T, C, **blocks, interpret=interpret)
     else:
-        bi = _fit(cfg.get("bi", 512), I)
-        out = support_count_mxu(T, C, bn=bn, bm=bm, bi=bi,
-                                interpret=interpret)
+        out = support_count_mxu(T, C, **blocks, interpret=interpret)
     counts = out[0, :M0]
     # padded transaction rows are all-zero: they can only match |c|=0 sets,
     # which do not occur among real candidates (Apriori starts at k=1).
     return counts
+
+
+def _launch(N: int, M: int, I: int, tuning, interpret):
+    """``(variant, block sizes, interpret)`` of one launch at the padded
+    shape ``(N, M, I)``: the dispatched config, each block shrunk to
+    divide its dim."""
+    cfg, interpret = dispatch("support_count", (N, M, I), tuning, interpret)
+    variant = cfg.get("variant", "mxu")
+    blocks = {"bn": _fit(cfg.get("bn", 512), N),
+              "bm": _fit(cfg.get("bm", 256), M)}
+    if variant != "packed":
+        blocks["bi"] = _fit(cfg.get("bi", 512), I)
+    return variant, blocks, interpret
+
+
+def support_count_tiles(tiles: jnp.ndarray, C: jnp.ndarray, *,
+                        combine_fn=operator.add,
+                        interpret: bool | None = None,
+                        tuning=None) -> jnp.ndarray:
+    """Support counts [M] int32 of C over every row tile of ``tiles``
+    (``[n_tiles, N, I]`` 0/1 int8, ``N % 8 == 0``, ``I % 128 == 0``), as
+    one device program: C is cast and |C| taken once, the config resolved
+    once at the tile shape ``(N, M, I)`` — the shape :func:`support_count`
+    resolves a tile at — and the kernel launched once per tile, each
+    launch the same as :func:`support_count`'s, the partial counts folded
+    in the program by ``combine_fn`` (a round's combiner, traced into the
+    program).  ``tuning`` as for :func:`support_count`."""
+    M0 = C.shape[0]
+    if M0 == 0:
+        return jnp.zeros((0,), jnp.int32)
+    _, N, I = tiles.shape
+    if N % 8 or I % 128 or C.shape[1] != I:
+        raise ValueError(f"tiles {tiles.shape} and candidates {C.shape} "
+                         f"are not aligned to the kernel")
+    C = _pad_to(C, 0, 128)
+    variant, blocks, interpret = _launch(N, C.shape[0], I, tuning,
+                                         interpret)
+    out = _count_tiles(tiles, C, combine_fn=combine_fn, variant=variant,
+                       interpret=interpret, **blocks)
+    return out if out.shape[0] == M0 else out[:M0]
+
+
+@functools.partial(jax.jit, static_argnames=("combine_fn", "variant", "bn",
+                                             "bm", "bi", "interpret"))
+def _count_tiles(tiles, C, *, combine_fn, variant: str, bn: int, bm: int,
+                 bi: int | None = None, interpret: bool = False):
+    """The round program of :func:`support_count_tiles`: a scan over the
+    tiles, one kernel launch per step, keeping the kernels' own names
+    (``support_count_pallas`` / ``support_count_fused_pallas``)."""
+    C = C.astype(jnp.int8)
+    sizes = jnp.sum(C, axis=1, dtype=jnp.int32)[None, :]       # [1, M]
+    if variant == "packed":
+        Cw = pack_words(C)
+        count = lambda T: support_count_fused_pallas(
+            pack_words(T), Cw, sizes, bn=bn, bm=bm, interpret=interpret)
+    else:
+        count = lambda T: support_count_pallas(
+            T, C, sizes, bn=bn, bm=bm, bi=bi, interpret=interpret)
+
+    def step(acc, T):
+        return combine_fn(acc, count(T)[0]), None
+
+    acc, _ = jax.lax.scan(step, jnp.zeros((C.shape[0],), jnp.int32), tiles)
+    return acc
 
 
 def intersect_count(A: jnp.ndarray, B: jnp.ndarray, *,

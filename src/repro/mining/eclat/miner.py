@@ -184,12 +184,8 @@ class EclatMiner:
             result, rep = self.cluster.run(job, tiles, failures=failures,
                                            speculate=self.config.speculate,
                                            assignment=asg)
-            result = finalize(result)       # the round's single sync
-            return MeasuredPhase(result=result, busy_s=rep.busy_s,
-                                 makespan=rep.makespan,
-                                 switches=rep.switches, reissued=rep.reissued,
-                                 failed_devices=list(rep.failed_devices),
-                                 tiles_done=rep.tiles_done)
+            # the round's single sync
+            return MeasuredPhase.from_exec(finalize(result), rep)
 
         return self.runtime.run_phase(
             task, execute, tile_costs=tile_costs,

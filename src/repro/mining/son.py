@@ -362,12 +362,7 @@ class SONMiner:
             result, rep = self.cluster.run(job, tiles, failures=None,
                                            speculate=self.config.speculate,
                                            assignment=asg)
-            return MeasuredPhase(result=finalize(result), busy_s=rep.busy_s,
-                                 makespan=rep.makespan,
-                                 switches=rep.switches,
-                                 reissued=rep.reissued,
-                                 failed_devices=list(rep.failed_devices),
-                                 tiles_done=rep.tiles_done)
+            return MeasuredPhase.from_exec(finalize(result), rep)
 
         chunk_counts, _ = rt.run_phase(
             task, execute, tile_costs=tile_costs,

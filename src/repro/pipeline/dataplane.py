@@ -21,6 +21,8 @@ true candidate count rather than trusting zeros.
 from __future__ import annotations
 
 import functools
+import operator
+from collections.abc import Sequence
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -30,10 +32,27 @@ import jax.numpy as jnp
 
 from repro.data.baskets import ITEM_LANES
 from repro.kernels.support_count.ops import support_count as _pallas_count
+from repro.kernels.support_count.ops import support_count_tiles
 from repro.kernels.support_count.ref import support_count_ref as _ref_count
 from repro.runtime.transfers import METER, TransferMeter
 
 _jitted_ref = jax.jit(_ref_count)
+
+
+@functools.partial(jax.jit, static_argnames=("combine_fn",))
+def _ref_round(tiles: jax.Array, C: jax.Array, combine_fn) -> jax.Array:
+    """The jnp oracle's round program: :func:`support_count_tiles`'s scan
+    over the tiles with the reference as its count."""
+    def step(acc, T):
+        return combine_fn(acc, _ref_count(T, C)), None
+    return jax.lax.scan(step, jnp.zeros((C.shape[0],), jnp.int32), tiles)[0]
+
+
+@jax.jit
+def item_counts(tiles: jax.Array) -> jax.Array:
+    """Per-item transaction counts [I] int32 over every resident tile —
+    round 1 as one program."""
+    return jnp.sum(tiles, axis=(0, 1), dtype=jnp.int32)
 
 
 def resolve_backend(kind: str = "auto") -> str:
@@ -82,28 +101,50 @@ def uniform_tiles(T: np.ndarray, n_tiles: int,
 
 @functools.partial(jax.jit, static_argnames=("shape", "n_tiles"))
 def device_tiles(flat: jax.Array, shape: Tuple[int, int],
-                 n_tiles: int) -> Tuple[jax.Array, ...]:
-    """``uniform_tiles(pad_items(T), n_tiles)``, built on the device from
-    the raw bitmap ``T`` of ``shape``, uploaded as its flat bytes: one
-    program reshapes it, zero-pads the item axis to the lane multiple and
-    the rows to the tiling, then cuts the tiles.  The host uploads the
-    bitmap once and copies it never; a 1-D upload also spares the host
-    the 2-D array's relayout to the device's tiled layout, which 1,000
-    unaligned lanes need (TPU v5e: 0.018 s to a ready tile set, against
-    0.034-0.039 s for the 2-D upload)."""
+                 n_tiles: int) -> jax.Array:
+    """``uniform_tiles(pad_items(T), n_tiles)`` stacked into one
+    ``[n_tiles, rows, I]`` int8 array (the dtype the kernels read), built
+    on the device from the raw bitmap ``T`` of ``shape``, uploaded as its
+    flat bytes: one program reshapes it, zero-pads the item axis to the
+    lane multiple and the rows to the tiling, casts, and views the rows as
+    tiles.  The host uploads the bitmap once and copies it never; a 1-D
+    upload also spares the host the 2-D array's relayout to the device's
+    tiled layout, which 1,000 unaligned lanes need (TPU v5e: 0.018 s to a
+    ready tile set, against 0.034-0.039 s for the 2-D upload)."""
     n_tx, n_items = shape
     n_tiles, rows = tile_geometry(n_tx, n_tiles)
     padded = jnp.pad(flat.reshape(shape), ((0, rows * n_tiles - n_tx),
                                            (0, (-n_items) % ITEM_LANES)))
-    return tuple(padded[i * rows:(i + 1) * rows] for i in range(n_tiles))
+    return padded.astype(jnp.int8).reshape(n_tiles, rows, -1)
+
+
+class ResidentTiles(Sequence):
+    """The bitmap's row tiles, resident on the device as one
+    ``[n_tiles, rows, I]`` int8 array (``stack``) that a round program
+    reads whole.  As a sequence it holds each tile's shape and dtype — what
+    the scheduler plans and prices a tile by — so planning slices nothing
+    on the device."""
+
+    def __init__(self, stack: jax.Array):
+        self.stack = stack
+        self._tile = jax.ShapeDtypeStruct(stack.shape[1:], stack.dtype)
+
+    def __len__(self) -> int:
+        return int(self.stack.shape[0])
+
+    def __getitem__(self, i: int) -> jax.ShapeDtypeStruct:
+        if not -len(self) <= i < len(self):
+            raise IndexError(i)
+        return self._tile
 
 
 class DataPlane:
-    """Per-level candidate batch + per-tile support counting.
+    """Per-level candidate batch + support counting.
 
     Usage: ``prepare(C)`` (host bitmap) or ``prepare_device(C)`` once per
-    Apriori level, then ``tile_counts_device(tile)`` for every transaction
-    tile (this is the MapReduceJob's map_fn).
+    Apriori level, then ``round_counts(tiles)`` once over the resident
+    tiles (a MapReduceJob's round_fn), or ``tile_counts_device(tile)`` for
+    every transaction tile (a MapReduceJob's map_fn).
     """
 
     def __init__(self, kind: str = "auto", m_bucket: int = 128,
@@ -148,6 +189,19 @@ class DataPlane:
             return _pallas_count(Tj, self._C, interpret=self.interpret,
                                  tuning=self.tuning)
         return _jitted_ref(Tj, self._C)
+
+    def round_counts(self, tiles: ResidentTiles,
+                     combine_fn=operator.add) -> jnp.ndarray:
+        """Device-resident counts [m_padded] int32 over every resident
+        tile, the tiles' partial counts folded by ``combine_fn`` inside one
+        program (one host call a round) — no readback, no sync."""
+        assert self._C is not None, "prepare() before round_counts()"
+        if self.backend == "pallas":
+            return support_count_tiles(tiles.stack, self._C,
+                                       combine_fn=combine_fn,
+                                       interpret=self.interpret,
+                                       tuning=self.tuning)
+        return _ref_round(tiles.stack, self._C, combine_fn)
 
     def tile_counts_device(self, tile) -> jnp.ndarray:
         """Device-resident counts [m_padded] int32 for one tile — no slice,

@@ -10,6 +10,9 @@ Composition (paper §V):
      │             (one core runs, the rest are power-gated)
      │             tiled support counting       → Runtime.run_phase
      │             (DataPlane: Pallas kernel on TPU, jitted ref elsewhere)
+     │   every counting round runs as one device program over the resident
+     │   tiles; the scheduler plans and prices the tiles, it does not set
+     │   how many calls a round makes
      ├─ rules: confidence/lift pruning, serial phase on the fastest core
      ▼
   PipelineResult(supports, rules, PipelineReport)
@@ -25,13 +28,12 @@ policy planned.
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
-
-import jax.numpy as jnp
 
 from repro.core.hetero import HeterogeneityProfile
 from repro.core.itemsets import AprioriResult, frequent_itemsets
@@ -41,10 +43,11 @@ from repro.core.scheduler import MBScheduler, TaskSpec
 from repro.core.rules import Rule, generate_rules
 from repro.data.baskets import pack_transactions
 from repro.data.sparse import SparseSlab
-from repro.pipeline.dataplane import DataPlane, device_tiles
+from repro.pipeline.dataplane import (DataPlane, ResidentTiles, device_tiles,
+                                      item_counts)
 from repro.pipeline.devgen import DeviceLattice
 from repro.pipeline.report import PipelineReport, RoundReport
-from repro.runtime import (MeasuredPhase, Runtime, SlabPool, SwitchingPolicy,
+from repro.runtime import (MeasuredPhase, Runtime, SwitchingPolicy,
                            autotuned_costmodel, donated_add)
 
 Baskets = Union[np.ndarray, SparseSlab, Sequence[Sequence[int]]]
@@ -195,8 +198,6 @@ class MarketBasketPipeline:
                                     interpret=cfg.interpret,
                                     tuning=None if cfg.autotune else False,
                                     meter=self.runtime.meter)
-        # round-persistent donated count accumulators, keyed by bucket shape
-        self.slabs = SlabPool()
 
     # ------------------------------------------------------------------
     # phases
@@ -208,11 +209,11 @@ class MarketBasketPipeline:
     def _stage(self, baskets: Baskets):
         """The mine's first two serial phases.  ``mba-ingest`` validates
         and packs the baskets into the raw bitmap; ``mba-upload`` sends its
-        flat bytes to the device once and builds the lane-padded row tiles
-        there (``device_tiles``) — every round's map phase reuses them, so
-        uploading per round would redo the same transfers — and carries
-        the bitmap's bytes on its record.  Returns ``(device tiles,
-        lane-padded bitmap shape, raw item count, raw tx count)``."""
+        flat bytes to the device once and builds the lane-padded int8 row
+        tiles there (``device_tiles``) — every round's map phase reuses
+        them, so uploading per round would redo the same transfers — and
+        carries the bitmap's bytes on its record.  Returns ``(resident
+        tiles, lane-padded bitmap shape, raw item count, raw tx count)``."""
         cfg, rt = self.config, self.runtime
         n_rows = (baskets.n_tx if isinstance(baskets, SparseSlab)
                   else len(baskets))
@@ -222,12 +223,12 @@ class MarketBasketPipeline:
             min_speed=cfg.serial_min_speed)
         tiles, _ = rt.run_serial(
             "mba-upload", cost=max(1.0, float(T.nbytes)),
-            fn=lambda: list(device_tiles(rt.meter.h2d(T.reshape(-1)),
-                                         T.shape, cfg.n_tiles)),
+            fn=lambda: ResidentTiles(device_tiles(rt.meter.h2d(T.reshape(-1)),
+                                                  T.shape, cfg.n_tiles)),
             min_speed=cfg.serial_min_speed)
         return tiles, (n_tx_raw, tiles[0].shape[1]), n_items_raw, n_tx_raw
 
-    def _map_round(self, job: MapReduceJob, tiles: List,
+    def _map_round(self, job: MapReduceJob, tiles: ResidentTiles,
                    failures: Optional[List[FailureEvent]],
                    tile_flops: Optional[np.ndarray] = None,
                    finalize=None):
@@ -250,11 +251,7 @@ class MarketBasketPipeline:
                                            assignment=asg)
             if finalize is not None:
                 result = finalize(result)
-            return MeasuredPhase(result=result, busy_s=rep.busy_s,
-                                 makespan=rep.makespan,
-                                 switches=rep.switches, reissued=rep.reissued,
-                                 failed_devices=list(rep.failed_devices),
-                                 tiles_done=rep.tiles_done)
+            return MeasuredPhase.from_exec(result, rep)
 
         return self.runtime.run_phase(task, execute, tile_costs=tile_costs,
                                       tile_flops=tile_flops)
@@ -262,13 +259,14 @@ class MarketBasketPipeline:
     # ------------------------------------------------------------------
     def run(self, baskets: Baskets,
             failures: Optional[List[FailureEvent]] = None) -> PipelineResult:
-        """Mine ``baskets`` with every counting round held on device: all tile
-        kernels of a round dispatch eagerly (nothing in the map fan-out
-        synchronizes), partial counts fold into a donated slab accumulator, candidate generation for the next level runs as a
-        jitted join on the compacted frequent matrix, and the only
-        device→host crossing per counting round is one packed
-        ``[m_cap + 1]`` vector (counts + next join size) read inside the
-        map phase.  Itemset tuples reach the host once, at rule time."""
+        """Mine ``baskets`` with every counting round held on device: a
+        round is one device program over the resident tiles (one kernel
+        launch per tile, partial counts summed in the program), candidate
+        generation for the next level runs as a jitted join on the
+        compacted frequent matrix, and the only device→host crossing per
+        counting round is one packed ``[m_cap + 1]`` vector (counts + next
+        join size) read inside the map phase.  Itemset tuples reach the
+        host once, at rule time."""
         cfg = self.config
         rt = self.runtime
         t_start = time.perf_counter()
@@ -294,12 +292,8 @@ class MarketBasketPipeline:
                                 meter=rt.meter)
 
         # ---- round k=1: item frequency, one readback ------------------
-        job1 = MapReduceJob(
-            name="mba-round1-item-counts",
-            map_fn=lambda tile: tile.sum(axis=0, dtype=jnp.int32),
-            combine_fn=donated_add,
-            zero_fn=lambda: jnp.zeros(n_items, jnp.int32),
-        )
+        job1 = MapReduceJob(name="mba-round1-item-counts",
+                            round_fn=lambda ts: item_counts(ts.stack))
         counts, rec = self._map_round(
             job1, tiles, failures, tile_flops=tile_rows * n_items,
             finalize=lambda acc: rt.meter.d2h(acc, dtype=np.int64))
@@ -330,18 +324,16 @@ class MarketBasketPipeline:
                 break
             C, valid_c, bitmap, m_cap = gen
             self.data_plane.prepare_device(bitmap)
+            # the tiles' partial counts fold with the round's combiner
+            # inside the one program
             job = MapReduceJob(
                 name=f"mba-round{k}-support",
-                map_fn=self.data_plane.tile_counts_device,
-                combine_fn=donated_add,
-                zero_fn=lambda m=m_cap: self.slabs.take((m,), jnp.int32),
-            )
+                round_fn=functools.partial(self.data_plane.round_counts,
+                                           combine_fn=donated_add))
 
-            def finalize(acc, C=C, valid_c=valid_c):
-                packed, Fn, vn = lattice.finalize(acc, C, valid_c, min_sup)
-                host = rt.meter.d2h(packed)    # the round's single sync
-                self.slabs.give(acc)           # accumulator back to the pool
-                return host, Fn, vn
+            def finalize(counts, C=C, valid_c=valid_c):
+                packed, Fn, vn = lattice.finalize(counts, C, valid_c, min_sup)
+                return rt.meter.d2h(packed), Fn, vn   # the round's one sync
 
             (packed, Fn, vn), rec = self._map_round(
                 job, tiles, failures,

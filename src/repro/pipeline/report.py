@@ -47,6 +47,9 @@ class RoundReport:
     serial: Optional[PhaseRecord] = None    # None for k=1 (no candidate gen)
     m_padded: int = 0             # data-plane candidate batch (0 = host path)
     failed_devices: List[int] = field(default_factory=list)
+    # host calls the round's executor made into the data plane: 1 for a
+    # round run as one device program, n_tiles for a per-tile fold
+    map_calls: int = 0
 
     @property
     def time_s(self) -> float:
@@ -72,7 +75,8 @@ class RoundReport:
                    switches=map_phase.switches, reissued=map_phase.reissued,
                    energy_j=map_phase.energy_j, serial=serial,
                    m_padded=m_padded,
-                   failed_devices=list(map_phase.failed_devices))
+                   failed_devices=list(map_phase.failed_devices),
+                   map_calls=map_phase.map_calls)
 
 
 @dataclass
@@ -189,7 +193,7 @@ class PipelineReport:
             f"{self.n_tiles} tiles, min_support={self.min_support}",
             f"  {'round':>7s} {'cands':>6s} {'freq':>6s} {'serial_s':>9s} "
             f"{'map_s':>9s} {'energy_J':>9s} {'sw':>3s} {'re':>3s} "
-            f"{'tiles/core':>14s} {'Mpad':>5s}",
+            f"{'tiles/core':>14s} {'Mpad':>5s} {'calls':>5s}",
         ]
         for r in self.rounds:
             ser = r.serial.sim_time_s if r.serial else 0.0
@@ -198,7 +202,8 @@ class PipelineReport:
                 f"  {('k=' + str(r.k)):>7s} {r.n_candidates:6d} {r.n_frequent:6d} "
                 f"{ser:9.4f} {r.map_makespan_s:9.4f} {e:9.1f} "
                 f"{r.switches:3d} {r.reissued:3d} "
-                f"{'/'.join(map(str, r.tiles_per_device)):>14s} {r.m_padded:5d}")
+                f"{'/'.join(map(str, r.tiles_per_device)):>14s} "
+                f"{r.m_padded:5d} {r.map_calls:5d}")
         if self.rules_phase:
             lines.append(f"  rules: {self.n_rules} rules on core "
                          f"{self.rules_phase.device} "

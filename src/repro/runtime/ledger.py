@@ -74,6 +74,10 @@ class PhaseRecord:
     # repeated phase reads 0 lowerings.
     lowerings: int = 0
     compile_s: float = 0.0
+    # map phases: the host calls the executor made into the data plane to
+    # run the phase — 1 for a round run as one device program, one per
+    # tile for a per-tile fold (0 where the executor does not say)
+    map_calls: int = 0
 
 
 @dataclass

@@ -65,6 +65,16 @@ class MeasuredPhase:
     h2d_bytes: int = 0
     d2h_bytes: int = 0
     syncs: int = 0
+    map_calls: int = 0     # host calls the executor made to run the phase
+
+    @classmethod
+    def from_exec(cls, result: Any, rep) -> "MeasuredPhase":
+        """What a ``SimulatedCluster.run`` report observed, with the
+        phase's result."""
+        return cls(result=result, busy_s=rep.busy_s, makespan=rep.makespan,
+                   switches=rep.switches, reissued=rep.reissued,
+                   failed_devices=list(rep.failed_devices),
+                   tiles_done=rep.tiles_done, map_calls=rep.map_calls)
 
 
 def span_name(name: str) -> str:
@@ -264,7 +274,8 @@ class Runtime:
             h2d_bytes=xfer.h2d_bytes + measured.h2d_bytes,
             d2h_bytes=xfer.d2h_bytes + measured.d2h_bytes,
             syncs=xfer.syncs + measured.syncs,
-            lowerings=compiles.lowerings, compile_s=compiles.compile_s))
+            lowerings=compiles.lowerings, compile_s=compiles.compile_s,
+            map_calls=measured.map_calls))
         return measured.result, rec
 
     # ------------------------------------------------------------------

@@ -171,8 +171,8 @@ def test_binary_bitmap_of_any_dtype_mines_alike(dtype):
                                            (96, 4)])
 def test_device_tiles_match_host_tiling(n_items, n_tx, n_tiles):
     """The device-built tiles are byte for byte the host tiling of the
-    lane-padded bitmap: same count, shapes and dtype (7 rows in 32 tiles
-    clamps the count to the rows)."""
+    lane-padded bitmap, stacked and in the kernels' int8: same count and
+    tile shape (7 rows in 32 tiles clamps the count to the rows)."""
     import jax.numpy as jnp
     from repro.data.baskets import pad_items
     from repro.pipeline.dataplane import device_tiles, uniform_tiles
@@ -180,10 +180,10 @@ def test_device_tiles_match_host_tiling(n_items, n_tx, n_tiles):
     T = (rng.random((n_tx, n_items)) < 0.1).astype(np.uint8)
     want = uniform_tiles(pad_items(T), n_tiles)
     got = device_tiles(jnp.asarray(T.reshape(-1)), T.shape, n_tiles)
-    assert len(got) == len(want)
-    for g, w in zip(got, want):
-        assert g.shape == w.shape and g.dtype == w.dtype
-        np.testing.assert_array_equal(np.asarray(g), w)
+    assert got.shape == (len(want),) + want[0].shape
+    assert got.dtype == jnp.int8
+    for g, w in zip(np.asarray(got), want):
+        np.testing.assert_array_equal(g, w.astype(np.int8))
 
 
 def test_failure_energy_bills_replanned_core_as_active():

@@ -1,9 +1,12 @@
 """Device-resident round execution: the transfer ledger is exact for a
 scripted two-round mine, every counting round of the Apriori, Eclat and
-streaming miners makes exactly one d2h sync, the on-device candidate
-join/prune matches the host generate_candidates bit for bit (including the
-guarded host fallback), and Apriori and Eclat mine the oracle's
-supports/rules under every switching policy."""
+streaming miners makes exactly one d2h sync, an Apriori round is one call
+into the data plane whose counts equal the per-tile fold's, the on-device
+candidate join/prune matches the host generate_candidates bit for bit
+(including the guarded host fallback), and Apriori and Eclat mine the
+oracle's supports/rules under every switching policy."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -16,7 +19,9 @@ from repro.data.baskets import (BasketConfig, generate_baskets,
                                 stationary_baskets)
 from repro.mining import EclatMiner
 from repro.pipeline import MarketBasketPipeline, PipelineConfig
-from repro.pipeline.dataplane import pad_candidates
+from repro.pipeline import pipeline as pipeline_module
+from repro.pipeline.dataplane import (DataPlane, ResidentTiles, device_tiles,
+                                      pad_candidates)
 from repro.pipeline.devgen import DeviceLattice
 from repro.streaming import StreamingConfig, StreamingMiner, TransactionStream
 
@@ -145,6 +150,100 @@ def test_streaming_delta_and_validation_read_back_once():
     assert len(validate) >= 2 and report.n_revalidations >= 2
     assert all(p.syncs == 1 for p in delta + validate), \
         [(p.name, p.syncs) for p in delta + validate if p.syncs != 1]
+
+
+# ---------------------------------------------------------------------------
+# a counting round is one call into the data plane
+# ---------------------------------------------------------------------------
+
+# the data planes a round program runs on here: the jitted jnp oracle, and
+# the Pallas kernels in interpret mode
+PLANES = {"ref": dict(kind="ref"),
+          "pallas": dict(kind="pallas", interpret=True)}
+
+
+def _pair_candidates(T, min_sup, source):
+    """The k=2 candidate bitmap (device) and its itemsets, from the device
+    join, the host join (the device join's fallback past
+    ``max_candidates``) or a host bitmap staged with ``prepare``."""
+    n_items = T.shape[1] + (-T.shape[1]) % 128
+    items = np.nonzero(T.sum(axis=0) >= min_sup)[0]
+    want = generate_candidates([(int(i),) for i in items])
+    if source == "host-prepare":
+        return itemsets_to_bitmap(want, n_items), want
+    lat = DeviceLattice(n_items, max_candidates=0 if source == "host-join"
+                        else 1 << 17)
+    lat.seed_items(items)
+    C, valid_c, bitmap, _ = lat.join()
+    assert _decoded(C, valid_c) == want
+    return bitmap, want
+
+
+@pytest.mark.parametrize("source", ["device-join", "host-join",
+                                    "host-prepare"])
+@pytest.mark.parametrize("plane", sorted(PLANES))
+@pytest.mark.parametrize("n_tiles", [1, 3, 32])
+def test_round_program_counts_equal_the_tile_fold_and_the_oracle(
+        n_tiles, plane, source):
+    # 203 rows: a multiple of no tiling here, so the last tile is padded
+    T = generate_baskets(BasketConfig(n_tx=203, n_items=20, seed=n_tiles))
+    min_sup = 8
+    tiles = ResidentTiles(device_tiles(jnp.asarray(T.reshape(-1)), T.shape,
+                                       n_tiles))
+    assert len(tiles) == n_tiles
+    C, cands = _pair_candidates(T, min_sup, source)
+    dp = DataPlane(**PLANES[plane])
+    if source == "host-prepare":
+        dp.prepare(C)
+    else:
+        dp.prepare_device(C)
+
+    fused = np.asarray(dp.round_counts(tiles))
+    fold = sum(np.asarray(dp.tile_counts_device(t)) for t in tiles.stack)
+    np.testing.assert_array_equal(fused, fold)
+    assert fused.shape == (dp.m_padded,)
+    # each candidate's containment count, and the frequent ones are the
+    # brute-force oracle's pairs
+    want = np.array([int(T[:, list(c)].all(axis=1).sum()) for c in cands])
+    np.testing.assert_array_equal(fused[:len(cands)], want)
+    oracle = {c: s for c, s in apriori_bruteforce(T, min_sup,
+                                                  max_k=2).items()
+              if len(c) == 2}
+    assert {c: int(s) for c, s in zip(cands, fused)
+            if s >= min_sup} == oracle
+
+
+@pytest.mark.parametrize("join", ["device", "host"])
+@pytest.mark.parametrize("plane", sorted(PLANES))
+@pytest.mark.parametrize("n_tiles", [1, 3, 32])
+def test_every_pipeline_round_is_one_data_plane_call(n_tiles, plane, join,
+                                                     monkeypatch):
+    if join == "host":           # every level past the device join's limit
+        monkeypatch.setattr(pipeline_module, "DeviceLattice",
+                            functools.partial(DeviceLattice,
+                                              max_candidates=0))
+    T = generate_baskets(BasketConfig(n_tx=203, n_items=20, seed=7))
+    cfg = _mk_cfg(n_tiles=n_tiles, data_plane=PLANES[plane]["kind"],
+                  interpret=PLANES[plane].get("interpret"))
+    res = MarketBasketPipeline(config=cfg).run(T)
+    counted = [r for r in res.report.rounds if r.n_tiles]
+    assert len(counted) >= 3, "fixture must count three levels"
+    assert [r.map_calls for r in counted] == [1] * len(counted)
+    assert all(r.n_tiles == n_tiles and sum(r.tiles_per_device) == n_tiles
+               for r in counted)
+    assert "calls" in res.report.summary()
+    want_supports, want_rules = _oracle(T, cfg)
+    assert res.supports == want_supports
+    assert res.rules == want_rules
+
+
+def test_eclat_rounds_fold_per_tile():
+    T = generate_baskets(BasketConfig(n_tx=512, n_items=32, seed=5))
+    res = EclatMiner(config=_mk_cfg(algorithm="eclat")).run(T)
+    # Eclat tiles each round's candidate rows: one call per tile
+    counted = [r for r in res.report.rounds if r.n_tiles]
+    assert max(r.n_tiles for r in counted) > 1
+    assert [r.map_calls for r in counted] == [r.n_tiles for r in counted]
 
 
 # ---------------------------------------------------------------------------

@@ -27,7 +27,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.autotune.cache import default_cache, resolve_config
 from repro.kernels.rule_match.ops import rule_topk
-from repro.kernels.support_count.ops import intersect_count, support_count
+from repro.kernels.support_count.ops import (intersect_count, support_count,
+                                            support_count_tiles)
 from repro.launch.tuning import VMEM_BUDGET_BYTES, vmem_bytes
 
 V5E_KIND = "TPU_v5_lite"        # jax device_kind "TPU v5 lite", cache-keyed
@@ -103,6 +104,14 @@ def _lowered(kernel, shape, cfg, one_chip):
         n, m, i = shape
         fn = lambda T, C: support_count(T, C, interpret=False, tuning=cfg)
         return jax.jit(fn).lower(S((n, i), jnp.int8), S((m, i), jnp.int8))
+    if kernel == "support_count_tiles":
+        # a whole counting round: the resident int8 tiles and the uint8
+        # candidate bitmap the device join builds
+        t, n, m, i = shape
+        fn = lambda tiles, C: support_count_tiles(tiles, C, interpret=False,
+                                                  tuning=cfg)
+        return jax.jit(fn).lower(S((t, n, i), jnp.int8),
+                                 S((m, i), jnp.uint8))
     if kernel == "intersect_count":
         fn = lambda A, B: intersect_count(A, B, interpret=False, tuning=cfg)
         return jax.jit(fn).lower(S(shape, jnp.uint32), S(shape, jnp.uint32))
@@ -139,12 +148,20 @@ NAMED = [
      {"variant": "packed", "bb": 64, "br": 128}, "rule_scores_fused_pallas"),
     ("rule_match", (512, 896, 1024),
      {"variant": "mxu", "bb": 64, "br": 128, "bi": 512}, "rule_scores_pallas"),
+    # the benchmark's k=2 round as one program: 32 resident tiles of
+    # 3,128 x 1,024 against 113,152 candidates, at the config the chip
+    # resolves for one tile
+    ("support_count_tiles", (32, 3128, 113152, 1024), None,
+     "support_count_pallas"),
 ]
 
 
 @pytest.mark.parametrize("kernel,shape,pinned,name", NAMED,
-                         ids=[n for *_, n in NAMED])
+                         ids=[n if k != "support_count_tiles" else f"{k}-{n}"
+                              for k, _, _, n in NAMED])
 def test_packed_kernel_keeps_its_name_in_v5e_hlo(kernel, shape, pinned, name,
                                                  one_chip):
+    if pinned is None:               # a round resolves at its tile's shape
+        pinned = _config("support_count", shape[1:], None)
     hlo = _lowered(kernel, shape, pinned, one_chip).compile().as_text()
     assert name in hlo
